@@ -25,12 +25,12 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import tempfile
 import time
 from typing import Any, Dict
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.runner_rl import AsyncRLRunConfig, run_async_rl
 
 
@@ -39,23 +39,17 @@ def _compilation_cache():
     """Persist XLA executables so the warm run actually warms the timed
     run: each run_async_rl builds fresh jit wrappers (whose per-wrapper
     caches are useless across calls), but the persistent cache is keyed
-    on the HLO fingerprint and is shared.  Restores the global config on
-    exit so later benchmarks in the same process measure under the
-    default (non-persisting) conditions."""
-    names = ("jax_compilation_cache_dir",
-             "jax_persistent_cache_min_compile_time_secs")
-    try:
-        saved = {n: getattr(jax.config, n) for n in names}
-        jax.config.update(names[0], tempfile.mkdtemp())
-        jax.config.update(names[1], 0.0)
-    except Exception:
-        yield  # older jax: timings will include trace+compile
-        return
+    on the HLO fingerprint and is shared.  The cache is the one the
+    caller's ``main()`` enabled (``repro.launch.compile_cache``); inside
+    this block even sub-second compiles are kept, and the threshold is
+    restored on exit."""
+    name = "jax_persistent_cache_min_compile_time_secs"
+    saved = getattr(jax.config, name)
+    jax.config.update(name, 0.0)
     try:
         yield
     finally:
-        for n, v in saved.items():
-            jax.config.update(n, v)
+        jax.config.update(name, saved)
 
 
 def run(
@@ -282,6 +276,7 @@ def main() -> None:
                          "shape as benchmarks.run's) for the CI "
                          "regression gate")
     args = ap.parse_args()
+    enable_compile_cache()
     res = run(phases=args.phases, n_actors=args.n_actors,
               rollout_steps=args.rollout_steps, algorithm=args.algorithm)
     for k, v in res.items():

@@ -18,7 +18,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import sys
 import time
 
 import numpy as np
@@ -151,18 +150,17 @@ def bench_serve_throughput(fast: bool, out_dir: str) -> None:
 
 
 def bench_theory() -> None:
-    """Appendix B numerical validation (tabular MDP) as a benchmark."""
+    """Appendix B numerical validation (tabular MDP) as a benchmark.
+
+    Runs in this process: a child started once JAX is live here could
+    not reach an accelerator this process holds."""
+    import pytest
+
     t0 = time.perf_counter()
-    import subprocess
-    r = subprocess.run(
-        [sys.executable, "-m", "pytest", "tests/test_theory.py", "-q",
-         "--no-header", "-x"],
-        capture_output=True, text=True,
-        env={**os.environ, "PYTHONPATH": "src"},
-    )
+    rc = pytest.main(["tests/test_theory.py", "-q", "--no-header", "-x",
+                      "-p", "no:cacheprovider"])
     us = (time.perf_counter() - t0) * 1e6
-    ok = "passed" in r.stdout and "failed" not in r.stdout
-    _row("appendixB_theory_validation", us, f"all_pass={ok}")
+    _row("appendixB_theory_validation", us, f"all_pass={rc == 0}")
 
 
 def bench_kernels() -> None:
@@ -201,6 +199,9 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     fast = args.fast or os.environ.get("REPRO_BENCH_FAST", "1") == "1"
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     bench_kernels()
     bench_theory()

@@ -1,9 +1,11 @@
 """Architecture registry: the 10 assigned configs + the paper's own model.
 
-``get_config(name)`` returns the FULL assigned config (dry-run only on
-this host); ``reduced_config(name)`` returns the CPU-smoke variant of the
-same family (<= 2 layers, d_model <= 512, <= 4 experts) used by tests and
-the runnable examples.
+``get_config(name)`` returns the FULL published config;
+``reduced_config(name)`` returns the CPU-smoke variant of the same family
+(<= 2 layers, d_model <= 512, <= 4 experts) used by tests and the
+runnable examples.  The launchers' ``--arch`` goes through
+``launch_config``: ``qwen2.5-0.5b`` is the published model and
+``qwen2.5-0.5b-reduced`` its CPU-smoke reduction.
 """
 from __future__ import annotations
 
@@ -101,6 +103,26 @@ def reduced_config(name: str, vocab: int = 512) -> ModelConfig:
     return cfg.replace(name=f"{cfg.name}-reduced", **changes)
 
 
+REDUCED_SUFFIX = "-reduced"
+
+
+def launch_config(name: str, vocab: int) -> ModelConfig:
+    """The config a launcher's ``--arch name`` builds.
+
+    ``<arch>-reduced`` is :func:`reduced_config` with the tokenizer's
+    ``vocab``; any other name is the published config, whose vocabulary
+    must hold the tokenizer's ``vocab`` ids (they are its first ids).
+    """
+    if name.endswith(REDUCED_SUFFIX):
+        return reduced_config(name[:-len(REDUCED_SUFFIX)], vocab=vocab)
+    cfg = get_config(name)
+    if cfg.vocab_size < vocab:
+        raise ValueError(
+            f"{name}: vocabulary {cfg.vocab_size} cannot hold the "
+            f"tokenizer's {vocab} ids")
+    return cfg
+
+
 __all__ = [
     "ARCHS",
     "EXTRA_ARCHS",
@@ -111,5 +133,6 @@ __all__ = [
     "SSMConfig",
     "list_archs",
     "get_config",
+    "launch_config",
     "reduced_config",
 ]

@@ -7,9 +7,10 @@ Selection policy (``KernelMode``):
                           (CPU validation of the TPU kernel bodies).
 * ``pallas``            — compiled Pallas (real TPU).
 
-Default comes from ``REPRO_KERNEL_MODE`` (falls back to ``reference`` on
-CPU hosts).  The wrappers keep one signature regardless of backend so the
-models/trainers never branch.
+The default comes from the platform: compiled ``pallas`` on a TPU and
+``reference`` everywhere else, so a chip never runs an oracle or the
+interpreter unless a caller asks for it with ``mode=``.  The wrappers keep
+one signature regardless of backend so the models/trainers never branch.
 
 **Mesh-sharded serve** (``mesh=`` on the paged ops): the paged KV pool
 shards its ``NB`` (page) axis over the mesh's ``data`` axis, and every
@@ -36,12 +37,10 @@ case of the same code path, not a sibling implementation.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as ref_mod
@@ -63,14 +62,14 @@ _VALID = ("reference", "pallas_interpret", "pallas")
 
 
 def kernel_mode() -> str:
-    mode = os.environ.get("REPRO_KERNEL_MODE", "reference")
-    if mode not in _VALID:
-        raise ValueError(f"REPRO_KERNEL_MODE={mode!r}; want one of {_VALID}")
-    return mode
+    """The platform's kernel path: compiled Pallas on TPU, else oracles."""
+    return "pallas" if jax.default_backend() == "tpu" else "reference"
 
 
 def _pallas_kwargs(mode: Optional[str]) -> Optional[dict]:
     mode = mode or kernel_mode()
+    if mode not in _VALID:
+        raise ValueError(f"kernel mode {mode!r}; want one of {_VALID}")
     if mode == "reference":
         return None
     return {"interpret": mode == "pallas_interpret"}
@@ -150,10 +149,10 @@ def paged_attention(
         return jax.lax.psum(out, axis_name)
 
     pool = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), pool, pool, P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(q, k_pages, v_pages, block_tables, context_lens,
       slot_shard.astype(jnp.int32))
 
@@ -192,10 +191,10 @@ def paged_attention_multi(
         return jax.lax.psum(out, axis_name)
 
     pool = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), pool, pool, P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(q, k_pages, v_pages, block_tables, context_lens,
       slot_shard.astype(jnp.int32))
 
@@ -238,10 +237,10 @@ def paged_attention_varlen(
         return jax.lax.psum(out, axis_name)
 
     pool = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), pool, pool, P(), P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(q, k_pages, v_pages, block_tables, row_start, row_len,
       slot_shard.astype(jnp.int32))
 
@@ -291,10 +290,10 @@ def paged_kv_write(
             kp, vp, kr, vr, pidx, off, local_act, layer=layer, mode=mode)
 
     pool = P(None, None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(pool, pool, P(), P(), P(), P(), P(), P()),
-        out_specs=(pool, pool), check_rep=False,
+        out_specs=(pool, pool), check_vma=False,
     )(k_pages, v_pages, k_rows, v_rows, page_idx, offset, active,
       slot_shard.astype(jnp.int32))
 

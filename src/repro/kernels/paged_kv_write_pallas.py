@@ -2,7 +2,7 @@
 
 The serve engine's decode step appends one K/V row per active slot into
 the pooled block cache.  Expressing that append as a jnp scatter on a
-scan-carried pool makes XLA rewrite the *entire* ``[L, KV, NB, BS, Dh]``
+scan-carried pool makes XLA rewrite the *entire* ``[L, KV, NB, BS, lanes]``
 pool every step — per-step cost grows linearly in ``num_blocks`` even
 though exactly one row per layer changes (ROADMAP: a 128-block pool
 measured ~2.7x slower than 16-block at equal work).  This kernel is the
@@ -15,15 +15,20 @@ write-side mirror of ``kernels/paged_attention_pallas.py``'s gather:
   (``pltpu.PrefetchScalarGridSpec``), so the destination of each row is
   known before the body runs — the scatter happens in the DMA engine
   (``pltpu.make_async_copy`` VMEM -> HBM), not in compute;
-* grid = (batch,): slot b DMAs its ``[KV, 1, 1, Dh]`` K and V rows into
-  ``pages[layer, :, page_idx[b], offset[b], :]``; inactive slots skip
-  the copy entirely with ``pl.when`` (the aliased buffer keeps its old
-  rows — "drop" semantics for free, and zero traffic for dead slots).
+* grid = (batch,): slot b DMAs its ``[KV, 1, 1, lanes]`` K and V rows
+  into ``pages[layer, :, page_idx[b], offset[b], :]``; inactive slots
+  skip the copy entirely with ``pl.when`` (the aliased buffer keeps its
+  old rows — "drop" semantics for free, and zero traffic for dead
+  slots).  Rows arrive ``[B, KV, D]`` and are zero-padded to the pool's
+  ``lanes`` (a multiple of 128): the TPU's DMA engine refuses a slice
+  narrower than the 128-lane tile, so a 64-wide row cannot be written.
 
-Distinct requests own distinct pages (the allocator guarantees it), so
-the per-slot DMAs never collide.  ``layer`` is static: the hoisted
-decode loop (``transformer.decode_step_paged``) emits one dispatch per
-layer against the stacked pool.
+Every row has its own destination (distinct requests own distinct pages,
+and one request's rows distinct positions), so the per-slot DMAs never
+collide; a ``[B, T]`` chunk is written as ``B * T`` slots in one call.
+``layer`` is static: the hoisted layer loop of the paged steps
+(``transformer.decode_step_paged*``) emits one dispatch per layer
+against the stacked pool.
 
 Forward-only; the pure-jnp oracle is
 ``repro.kernels.ref.ref_paged_kv_write`` (whose per-slot
@@ -40,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import pad_lanes
+
 
 def _kv_write_kernel(
     page_idx_ref,   # scalar prefetch [B] int32 (in-range for active slots)
@@ -47,8 +54,8 @@ def _kv_write_kernel(
     active_ref,     # scalar prefetch [B] int32 (0 = drop the write)
     k_rows_ref,     # [1, KV, 1, 1, D] VMEM — slot b's new K row
     v_rows_ref,     # [1, KV, 1, 1, D] VMEM
-    k_in_ref,       # [L, KV, NB, BS, D] ANY/HBM (aliased with k_out_ref)
-    v_in_ref,       # [L, KV, NB, BS, D] ANY/HBM (aliased with v_out_ref)
+    k_in_ref,       # [L, KV, NB, BS, lanes] ANY/HBM (aliased with k_out_ref)
+    v_in_ref,       # [L, KV, NB, BS, lanes] ANY/HBM (aliased with v_out_ref)
     k_out_ref,      # same buffer as k_in_ref
     v_out_ref,      # same buffer as v_in_ref
     k_sem,          # DMA semaphore
@@ -81,8 +88,8 @@ def _kv_write_kernel(
 
 @functools.partial(jax.jit, static_argnames=("layer", "interpret"))
 def paged_kv_write(
-    k_pages: jax.Array,   # [L, KV, NB, BS, D] pooled key blocks
-    v_pages: jax.Array,   # [L, KV, NB, BS, D] pooled value blocks
+    k_pages: jax.Array,   # [L, KV, NB, BS, lanes] pooled key blocks
+    v_pages: jax.Array,   # [L, KV, NB, BS, lanes] pooled value blocks
     k_rows: jax.Array,    # [B, KV, D] new key rows (one per slot)
     v_rows: jax.Array,    # [B, KV, D] new value rows
     page_idx: jax.Array,  # [B] int32 destination page per slot
@@ -98,8 +105,9 @@ def paged_kv_write(
     consumed, exactly like a donated buffer.  ``page_idx`` of an inactive
     slot may be any value (the copy is skipped before the id is read).
     """
-    b, kv, d = k_rows.shape
+    b, kv, _ = k_rows.shape
     assert k_pages.ndim == 5, k_pages.shape
+    d = k_pages.shape[4]      # the pool's (lane-padded) row width
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
@@ -108,12 +116,12 @@ def paged_kv_write(
                          lambda b_, pi, of, ac: (b_, 0, 0, 0, 0)),
             pl.BlockSpec((1, kv, 1, 1, d),
                          lambda b_, pi, of, ac: (b_, 0, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.SemaphoreType.DMA(()),
@@ -133,6 +141,6 @@ def paged_kv_write(
         interpret=interpret,
     )(page_idx.astype(jnp.int32), offset.astype(jnp.int32),
       active.astype(jnp.int32),
-      k_rows.reshape(b, kv, 1, 1, d).astype(k_pages.dtype),
-      v_rows.reshape(b, kv, 1, 1, d).astype(v_pages.dtype),
+      pad_lanes(k_rows, d).reshape(b, kv, 1, 1, d).astype(k_pages.dtype),
+      pad_lanes(v_rows, d).reshape(b, kv, 1, 1, d).astype(v_pages.dtype),
       k_pages, v_pages)

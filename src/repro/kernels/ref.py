@@ -80,8 +80,8 @@ def ref_attention(
 
 def ref_paged_attention(
     q: jax.Array,             # [B, H, D] one query token per request
-    k_pages: jax.Array,       # [KV, NB, BS, D] pooled key blocks
-    v_pages: jax.Array,       # [KV, NB, BS, D] pooled value blocks
+    k_pages: jax.Array,       # [KV, NB, BS, lanes >= D] pooled key blocks
+    v_pages: jax.Array,       # [KV, NB, BS, lanes >= D] pooled value blocks
     block_tables: jax.Array,  # [B, M] int32 page ids (pad slots may be any
                               # in-range id; they are masked by context_lens)
     context_lens: jax.Array,  # [B] int32 valid tokens per request (0 = slot
@@ -97,13 +97,13 @@ def ref_paged_attention(
     the newest token (the query's own K/V row) is expected to already be
     written at position ``context_lens[b] - 1``.
     """
-    kv, _, bs, d = k_pages.shape
-    b, h, _ = q.shape
+    kv = k_pages.shape[0]
+    b, h, d = q.shape
     g = h // kv
     scale = d ** -0.5
-    # [KV, B, M, BS, D] -> [KV, B, S, D] with S = M * BS
-    keys = k_pages[:, block_tables].reshape(kv, b, -1, d)
-    vals = v_pages[:, block_tables].reshape(kv, b, -1, d)
+    # [KV, B, M, BS, lanes] -> [KV, B, S, D] with S = M * BS
+    keys = k_pages[:, block_tables, :, :d].reshape(kv, b, -1, d)
+    vals = v_pages[:, block_tables, :, :d].reshape(kv, b, -1, d)
     qg = q.reshape(b, kv, g, d)
     scores = jnp.einsum("bkgd,kbsd->bkgs", qg * scale, keys,
                         preferred_element_type=jnp.float32)
@@ -123,8 +123,8 @@ def ref_paged_attention(
 
 def ref_paged_attention_varlen(
     q: jax.Array,             # [B, T, H, D] ragged query chunks, right-padded
-    k_pages: jax.Array,       # [KV, NB, BS, D] pooled key blocks
-    v_pages: jax.Array,       # [KV, NB, BS, D] pooled value blocks
+    k_pages: jax.Array,       # [KV, NB, BS, lanes >= D] pooled key blocks
+    v_pages: jax.Array,       # [KV, NB, BS, lanes >= D] pooled value blocks
     block_tables: jax.Array,  # [B, M] int32 page ids
     row_start: jax.Array,     # [B] int32 abs position of query row 0
     row_len: jax.Array,       # [B] int32 live rows per slot (0 = inactive)
@@ -141,14 +141,14 @@ def ref_paged_attention_varlen(
     Decode, speculative verify and chunked prefill tiles are all this
     one shape with different ``(row_start, row_len)`` tables.
     """
-    kv, _, bs, d = k_pages.shape
-    b, t, h, _ = q.shape
+    kv = k_pages.shape[0]
+    b, t, h, d = q.shape
     g = h // kv
     scale = d ** -0.5
     row_start = row_start.astype(jnp.int32)
     row_len = row_len.astype(jnp.int32)
-    keys = k_pages[:, block_tables].reshape(kv, b, -1, d)
-    vals = v_pages[:, block_tables].reshape(kv, b, -1, d)
+    keys = k_pages[:, block_tables, :, :d].reshape(kv, b, -1, d)
+    vals = v_pages[:, block_tables, :, :d].reshape(kv, b, -1, d)
     qg = q.reshape(b, t, kv, g, d)
     scores = jnp.einsum("btkgd,kbsd->bkgts", qg * scale, keys,
                         preferred_element_type=jnp.float32)
@@ -169,8 +169,8 @@ def ref_paged_attention_varlen(
 
 def ref_paged_attention_multi(
     q: jax.Array,             # [B, T, H, D] consecutive query tokens
-    k_pages: jax.Array,       # [KV, NB, BS, D] pooled key blocks
-    v_pages: jax.Array,       # [KV, NB, BS, D] pooled value blocks
+    k_pages: jax.Array,       # [KV, NB, BS, lanes >= D] pooled key blocks
+    v_pages: jax.Array,       # [KV, NB, BS, lanes >= D] pooled value blocks
     block_tables: jax.Array,  # [B, M] int32 page ids
     context_lens: jax.Array,  # [B] int32 rows live *including* the T chunk
     *,
@@ -198,6 +198,19 @@ def ref_paged_attention_multi(
 # ---------------------------------------------------------------------------
 
 
+def pad_lanes(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad the last axis of ``x`` to ``width``.
+
+    The paged pool stores each K/V row lane-padded (see
+    ``models.transformer.init_paged_cache``); rows are padded on their
+    way in and queries alike, so the pad lanes only ever add exact
+    zeros to a dot product."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def masked_inplace_update(
     arr: jax.Array,
     new: jax.Array,
@@ -220,8 +233,8 @@ def masked_inplace_update(
 
 
 def ref_paged_kv_write(
-    k_pages: jax.Array,   # [L, KV, NB, BS, D] pooled key blocks
-    v_pages: jax.Array,   # [L, KV, NB, BS, D] pooled value blocks
+    k_pages: jax.Array,   # [L, KV, NB, BS, lanes] pooled key blocks
+    v_pages: jax.Array,   # [L, KV, NB, BS, lanes] pooled value blocks
     k_rows: jax.Array,    # [B, KV, D] new key rows (one per slot)
     v_rows: jax.Array,    # [B, KV, D] new value rows
     page_idx: jax.Array,  # [B] int32 destination page per slot
@@ -242,9 +255,10 @@ def ref_paged_kv_write(
     copy; distinct slots never share a destination (allocator invariant),
     so the chain order is immaterial.
     """
-    b, kv, d = k_rows.shape
-    k_rows = k_rows.astype(k_pages.dtype)
-    v_rows = v_rows.astype(v_pages.dtype)
+    b, kv, _ = k_rows.shape
+    d = k_pages.shape[4]
+    k_rows = pad_lanes(k_rows, d).astype(k_pages.dtype)
+    v_rows = pad_lanes(v_rows, d).astype(v_pages.dtype)
     safe_page = jnp.where(active, page_idx, 0).astype(jnp.int32)
     offset = offset.astype(jnp.int32)
     zero = jnp.zeros((), jnp.int32)

@@ -44,11 +44,11 @@ def _ssm_kernel(
     a = a_ref[...].astype(jnp.float32)          # [IB, N]
 
     def step(t, _):
-        idx = (pl.dslice(0, 1), pl.dslice(t, 1), slice(None))
-        u_t = pl.load(u_ref, idx)[0, 0]
-        dt_t = pl.load(dt_ref, idx)[0, 0]
-        b_t = pl.load(b_ref, idx)[0, 0]
-        c_t = pl.load(c_ref, idx)[0, 0]
+        row = (0, pl.ds(t, 1), slice(None))
+        u_t = u_ref[row][0]
+        dt_t = dt_ref[row][0]
+        b_t = b_ref[row][0]
+        c_t = c_ref[row][0]
         u_t = u_t.astype(jnp.float32)
         dt_t = dt_t.astype(jnp.float32)
         b_t = b_t.astype(jnp.float32)
@@ -59,8 +59,7 @@ def _ssm_kernel(
         h = decay * h + (dt_t * u_t)[:, None] * b_t[None, :]
         h_scratch[...] = h
         y_t = jnp.sum(h * c_t[None, :], axis=1)              # [IB]
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y_t[None, None, :].astype(y_ref.dtype))
+        y_ref[0, pl.ds(t, 1), :] = y_t[None, :].astype(y_ref.dtype)
         return ()
 
     jax.lax.fori_loop(0, s_len, step, ())

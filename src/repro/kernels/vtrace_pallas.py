@@ -1,12 +1,13 @@
 """Pallas TPU kernel for V-trace advantage realignment (paper Eqs. 14-15).
 
 TPU adaptation of a GPU per-trajectory loop: the recurrence is sequential
-in time but embarrassingly parallel over trajectories, so the grid tiles
-the *batch* dimension to the VPU sublane width (8) and each kernel
-instance runs the backward time scan with its carry in vector registers.
-The whole [B_BLK, T] tile lives in VMEM (for T=1000 rollouts and fp32
-that's 8 x 1000 x 4B x 5 inputs ~ 160 KiB — comfortably under the
-~16 MiB/core VMEM budget; tiles of B_BLK=8 keep lane pressure low).
+in time but embarrassingly parallel over trajectories, so trajectories
+ride the 128 lanes and time the sublanes: each kernel instance runs the
+backward time scan over one ``[T, 128]`` tile, reading and writing one
+row (a dynamic sublane index, which the TPU supports; a dynamic lane
+index it does not) per step with its carry in vector registers.  For
+T=1000 and fp32 the seven tiles take 1000 x 128 x 4B x 7 ~ 3.6 MiB of
+VMEM.
 
 All five inputs are consumed in one pass; vs and advantages are produced
 together (the advantage needs v_{t+1}, available in the same sweep),
@@ -25,53 +26,44 @@ from jax.experimental import pallas as pl
 
 
 def _vtrace_kernel(
-    log_ratios_ref,   # [B_BLK, T]
-    values_ref,       # [B_BLK, T]
-    bootstrap_ref,    # [B_BLK, 1]
-    rewards_ref,      # [B_BLK, T]
-    discounts_ref,    # [B_BLK, T]
-    vs_ref,           # [B_BLK, T] out
-    adv_ref,          # [B_BLK, T] out
+    log_ratios_ref,   # [T, B_BLK] (time on sublanes, trajectories on lanes)
+    values_ref,       # [T, B_BLK]
+    bootstrap_ref,    # [1, B_BLK]
+    rewards_ref,      # [T, B_BLK]
+    discounts_ref,    # [T, B_BLK]
+    vs_ref,           # [T, B_BLK] out
+    adv_ref,          # [T, B_BLK] out
     *,
     t_len: int,
     rho_bar: float,
     c_bar: float,
     lam: float,
 ):
-    ratios = jnp.exp(log_ratios_ref[...].astype(jnp.float32))
-    rhos = jnp.minimum(rho_bar, ratios)
-    cs = lam * jnp.minimum(c_bar, ratios)
-    values = values_ref[...].astype(jnp.float32)
-    rewards = rewards_ref[...].astype(jnp.float32)
-    discounts = discounts_ref[...].astype(jnp.float32)
-    bootstrap = bootstrap_ref[...][:, 0].astype(jnp.float32)
+    bootstrap = bootstrap_ref[...]
 
-    # values_{t+1}: shift left, bootstrap in the last column.
-    values_tp1 = jnp.concatenate(
-        [values[:, 1:], bootstrap[:, None]], axis=1
-    )
-    deltas = rhos * (rewards + discounts * values_tp1 - values)
+    def row(ref, t):
+        return ref[pl.ds(t, 1), :]
 
-    # Backward scan over time; carry = (acc, v_{t+1}) per row.
+    # Backward scan over time; carry = (acc, vs_{t+1}, V_{t+1}) per lane,
+    # with acc_t = vs_t - V_t and both t+1 terms = bootstrap at t = T-1.
     def step(t_rev, carry):
-        acc, v_next = carry  # acc_t = vs_t - V_t
+        acc, vs_next, v_next = carry
         t = t_len - 1 - t_rev
-        delta_t = jax.lax.dynamic_slice_in_dim(deltas, t, 1, 1)[:, 0]
-        disc_t = jax.lax.dynamic_slice_in_dim(discounts, t, 1, 1)[:, 0]
-        c_t = jax.lax.dynamic_slice_in_dim(cs, t, 1, 1)[:, 0]
-        val_t = jax.lax.dynamic_slice_in_dim(values, t, 1, 1)[:, 0]
-        rew_t = jax.lax.dynamic_slice_in_dim(rewards, t, 1, 1)[:, 0]
-        acc = delta_t + disc_t * c_t * acc
+        ratio = jnp.exp(row(log_ratios_ref, t))
+        val_t = row(values_ref, t)
+        rew_t = row(rewards_ref, t)
+        disc_t = row(discounts_ref, t)
+        delta_t = jnp.minimum(rho_bar, ratio) * (
+            rew_t + disc_t * v_next - val_t)
+        acc = delta_t + disc_t * lam * jnp.minimum(c_bar, ratio) * acc
         vs_t = val_t + acc
-        adv_t = rew_t + disc_t * v_next - val_t
-        pl.store(vs_ref, (slice(None), pl.dslice(t, 1)),
-                 vs_t[:, None].astype(vs_ref.dtype))
-        pl.store(adv_ref, (slice(None), pl.dslice(t, 1)),
-                 adv_t[:, None].astype(adv_ref.dtype))
-        return acc, vs_t
+        adv_t = rew_t + disc_t * vs_next - val_t
+        vs_ref[pl.ds(t, 1), :] = vs_t
+        adv_ref[pl.ds(t, 1), :] = adv_t
+        return acc, vs_t, val_t
 
     zero = jnp.zeros_like(bootstrap)
-    jax.lax.fori_loop(0, t_len, step, (zero, bootstrap))
+    jax.lax.fori_loop(0, t_len, step, (zero, bootstrap, bootstrap))
 
 
 @functools.partial(
@@ -88,34 +80,33 @@ def vtrace_pallas(
     rho_bar: float = 1.0,
     c_bar: float = 1.0,
     lam: float = 1.0,
-    block_b: int = 8,
+    block_b: int = 128,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     b, t = log_ratios.shape
-    block_b = min(block_b, b)
     pad_b = (-b) % block_b
-    if pad_b:
-        padder = lambda x: jnp.pad(x, ((0, pad_b),) + ((0, 0),) * (x.ndim - 1))
-        log_ratios, values, rewards, discounts = map(
-            padder, (log_ratios, values, rewards, discounts))
-        bootstrap_value = jnp.pad(bootstrap_value, (0, pad_b))
     bp = b + pad_b
 
-    grid = (bp // block_b,)
-    row_spec = pl.BlockSpec((block_b, t), lambda i: (i, 0))
-    boot_spec = pl.BlockSpec((block_b, 1), lambda i: (i, 0))
+    def lanes(x):   # [B, ...] -> [..., Bp] f32, trajectories on lanes
+        x = jnp.pad(x.astype(jnp.float32),
+                    ((0, pad_b),) + ((0, 0),) * (x.ndim - 1))
+        return x.T if x.ndim == 2 else x[None, :]
+
+    row_spec = pl.BlockSpec((t, block_b), lambda i: (0, i))
+    boot_spec = pl.BlockSpec((1, block_b), lambda i: (0, i))
 
     vs, adv = pl.pallas_call(
         functools.partial(
             _vtrace_kernel, t_len=t, rho_bar=rho_bar, c_bar=c_bar, lam=lam,
         ),
-        grid=grid,
+        grid=(bp // block_b,),
         in_specs=[row_spec, row_spec, boot_spec, row_spec, row_spec],
         out_specs=[row_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((bp, t), jnp.float32),
-            jax.ShapeDtypeStruct((bp, t), jnp.float32),
+            jax.ShapeDtypeStruct((t, bp), jnp.float32),
+            jax.ShapeDtypeStruct((t, bp), jnp.float32),
         ],
         interpret=interpret,
-    )(log_ratios, values, bootstrap_value[:, None], rewards, discounts)
-    return vs[:b], adv[:b]
+    )(lanes(log_ratios), lanes(values), lanes(bootstrap_value),
+      lanes(rewards), lanes(discounts))
+    return vs.T[:b], adv.T[:b]

@@ -18,10 +18,17 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over however many devices the host actually has."""
+    """A ``(data, model)`` mesh over this host's devices.
+
+    Raises when the host has fewer devices than the mesh asks for: a
+    mesh that quietly shrank would serve unsharded under a sharded
+    name."""
     n = len(jax.devices())
     if data * model > n:
-        data, model = n, 1
+        raise ValueError(
+            f"mesh data={data},model={model} needs {data * model} "
+            f"devices; this host has {n} (CPU: set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=N before JAX starts)")
     return jax.make_mesh((data, model), ("data", "model"))
 
 

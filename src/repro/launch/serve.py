@@ -1,8 +1,12 @@
-"""Serving launcher: completion generation against a reduced assigned
+"""Serving launcher: completion generation against an assigned
 architecture (the actor side of the async RLVR loop).
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-0.5b \\
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-0.5b-reduced \\
       --engine continuous --requests 12 --mixed-lengths 4,8,16,32
+
+``--arch qwen2.5-0.5b`` serves the published widths (24 layers, vocab
+151,936; for a TPU), ``--arch qwen2.5-0.5b-reduced`` the 2-layer CPU
+smoke reduction.
 
 Two engines:
 
@@ -86,10 +90,10 @@ def _parse_draft(spec: str, args, bundle, params, tok):
     if spec.startswith("version:"):
         return ("version", int(spec.split(":", 1)[1]))
     if spec.startswith("model:"):
-        from repro.configs import reduced_config
+        from repro.configs import launch_config
         from repro.models.registry import build
 
-        dcfg = reduced_config(spec.split(":", 1)[1], vocab=tok.vocab_size)
+        dcfg = launch_config(spec.split(":", 1)[1], vocab=tok.vocab_size)
         dbundle = build(dcfg)
         dparams = dbundle.init(_jax.random.PRNGKey(args.seed + 7))
         return ("model", dbundle, dparams)
@@ -376,6 +380,9 @@ def main(argv=None) -> int:
                          "JSONL line at exit (flushed early on "
                          "SIGINT/SIGTERM)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.requests is None:
         args.requests = args.batch
     if args.controller and args.engine != "continuous":
@@ -383,7 +390,7 @@ def main(argv=None) -> int:
                          "(shadow admission runs over retired requests)")
 
     from repro.obs.tracer import make_tracer
-    from repro.resilience import install_flush_handlers
+    from repro.resilience import install_flush_handlers, restore_handlers
 
     tracer = make_tracer(args.trace_detail if args.trace else "off")
 
@@ -410,16 +417,16 @@ def main(argv=None) -> int:
             print(f"metrics: flushed -> {args.metrics_out}")
         _export_trace()
 
-    install_flush_handlers(_flush)
+    previous_handlers = install_flush_handlers(_flush)
 
-    from repro.configs import reduced_config
+    from repro.configs import launch_config
     from repro.data.mathgen import MathTaskDataset
     from repro.data.tokenizer import get_tokenizer
     from repro.models.registry import build
     from repro.checkpoint import load_checkpoint
 
     tok = get_tokenizer()
-    cfg = reduced_config(args.arch, vocab=tok.vocab_size)
+    cfg = launch_config(args.arch, vocab=tok.vocab_size)
     bundle = build(cfg)
     init_params = bundle.init(jax.random.PRNGKey(args.seed))
     params = init_params
@@ -434,14 +441,10 @@ def main(argv=None) -> int:
         sizes = parse_mesh_spec(args.mesh)
         if args.engine != "continuous":
             raise SystemExit("--mesh requires --engine continuous")
-        n_dev = len(jax.devices())
-        if sizes["data"] * sizes["model"] > n_dev:
-            raise SystemExit(
-                f"--mesh {args.mesh}: wants "
-                f"{sizes['data'] * sizes['model']} devices, host has "
-                f"{n_dev} (CPU: export XLA_FLAGS=--xla_force_host_"
-                f"platform_device_count=N before launching)")
-        mesh = make_debug_mesh(data=sizes["data"], model=sizes["model"])
+        try:
+            mesh = make_debug_mesh(data=sizes["data"], model=sizes["model"])
+        except ValueError as e:
+            raise SystemExit(f"--mesh {args.mesh}: {e}")
         print(f"serving over mesh {dict(mesh.shape)} "
               f"({len(mesh.devices.flat)} devices)")
 
@@ -474,6 +477,7 @@ def main(argv=None) -> int:
     if args.metrics_out and _flush_state.get("metrics") is not None:
         _flush_state["metrics"].export_jsonl(args.metrics_out)
         print(f"metrics: snapshot -> {args.metrics_out}")
+    restore_handlers(previous_handlers)
     return 0
 
 

@@ -16,15 +16,16 @@ deprecation shims):
       --env pendulum --algorithm vaco --runtime threaded \\
       --controller "tv_gate:delta=0.2,mode=downweight" --phases 30
 
-  # RLVR (forward-lag GRPO/VACO, §5.2) on a reduced assigned arch
+  # RLVR (forward-lag GRPO/VACO, §5.2) on the CPU-smoke reduction;
+  # --arch qwen2.5-0.5b trains the published widths (for a TPU)
   PYTHONPATH=src python -m repro.launch.train rlvr \\
-      --arch qwen2.5-0.5b --algorithm grpo_vaco --n-minibatches 8 \\
+      --arch qwen2.5-0.5b-reduced --algorithm grpo_vaco --n-minibatches 8 \\
       --phases 20 --runtime forward_n
 
   # RLVR with the ServeEngine as the rollout producer: real per-token
   # {version, log_beta} provenance under a scripted 2-back lag
   PYTHONPATH=src python -m repro.launch.train rlvr \\
-      --producer serve --forced-lag 2 \\
+      --arch qwen2.5-0.5b-reduced --producer serve --forced-lag 2 \\
       --controller "tv_gate:delta=0.05,mode=downweight" --phases 10
 
 On a real TPU cluster the same entry point runs under
@@ -127,6 +128,9 @@ def main(argv=None) -> int:
 
     rv = sub.add_parser("rlvr", help="forward-lag RLVR (§5.2)")
     rv.add_argument("--arch", default="qwen2.5-0.5b")
+    rv.add_argument("--layers", type=int, default=None,
+                    help="cut the model's depth to N layers, keeping its "
+                         "published widths (to fit one chip's memory)")
     rv.add_argument("--algorithm", default="grpo_vaco",
                     choices=["grpo", "grpo_vaco"])
     rv.add_argument("--n-minibatches", type=int, default=4)
@@ -149,6 +153,12 @@ def main(argv=None) -> int:
                     help="completion length (default: hp default)")
     rv.add_argument("--engine-max-batch", type=int, default=8,
                     help="serve producer: engine decode batch size")
+    rv.add_argument("--prompts-per-minibatch", type=int, default=16)
+    rv.add_argument("--completions-per-prompt", type=int, default=4)
+    rv.add_argument("--warmup-batch", type=int, default=64)
+    rv.add_argument("--eval-prompts", type=int, default=256)
+    rv.add_argument("--store-capacity", type=int, default=4,
+                    help="policy snapshots the store keeps resident")
     # Resilience (see repro.resilience and README "Fault tolerance").
     rv.add_argument("--fault-plan", default="", metavar="PLAN",
                     help="fault-injection plan, ';'-joined "
@@ -187,9 +197,12 @@ def main(argv=None) -> int:
                     "tv_gate_tokenwise"))
 
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.obs.tracer import make_tracer
-    from repro.resilience import install_flush_handlers
+    from repro.resilience import install_flush_handlers, restore_handlers
 
     tracer = make_tracer(args.trace_detail if args.trace else "off")
 
@@ -224,7 +237,7 @@ def main(argv=None) -> int:
                 print(f"metrics: flushed -> {args.metrics_out}")
         _export_trace()
 
-    install_flush_handlers(_flush)
+    previous_handlers = install_flush_handlers(_flush)
 
     if args.mode == "rl":
         from repro.train.runner_rl import AsyncRLRunConfig, run_async_rl
@@ -248,10 +261,11 @@ def main(argv=None) -> int:
             "runtime_stats": res.runtime_stats,
         }, indent=1))
         _export_trace()
+        restore_handlers(previous_handlers)
         return 0
 
     # rlvr
-    from repro.configs import reduced_config, get_config
+    from repro.configs import launch_config
     from repro.data.mathgen import MathTaskDataset
     from repro.data.tokenizer import get_tokenizer
     from repro.models.registry import build
@@ -259,7 +273,9 @@ def main(argv=None) -> int:
     from repro.checkpoint import save_checkpoint
 
     tok = get_tokenizer()
-    cfg = reduced_config(args.arch, vocab=tok.vocab_size)
+    cfg = launch_config(args.arch, vocab=tok.vocab_size)
+    if args.layers is not None:
+        cfg = cfg.replace(n_layers=args.layers)
     bundle = build(cfg)
     ds = MathTaskDataset(prompt_len=32, level=args.level)
     hp_kwargs = dict(
@@ -269,6 +285,11 @@ def main(argv=None) -> int:
         controller=_resolve_controller(args, delta=args.delta),
         producer=args.producer, forced_lag=args.forced_lag,
         engine_max_batch=args.engine_max_batch,
+        prompts_per_minibatch=args.prompts_per_minibatch,
+        completions_per_prompt=args.completions_per_prompt,
+        warmup_batch=args.warmup_batch,
+        eval_prompts=args.eval_prompts,
+        store_capacity=args.store_capacity,
         fault_plan=args.fault_plan, fault_seed=args.fault_seed,
         watchdog_restarts=args.watchdog_restarts,
         watchdog_backoff_ms=args.watchdog_backoff_ms,
@@ -282,7 +303,7 @@ def main(argv=None) -> int:
     trainer = RLVRTrainer(bundle, ds, hp, seed=args.seed, tracer=tracer)
     _flush_state["trainer"] = trainer
     wl = trainer.warmup()
-    print(f"[warmup] loss={wl:.4f} acc={trainer.evaluate(128):.3f}")
+    print(f"[warmup] loss={wl:.4f} acc={trainer.evaluate():.3f}")
     res = trainer.train(args.phases, eval_every=max(args.phases // 4, 1))
     step_summary = trainer.metrics.histogram("train_step_s").summary()
     print(json.dumps({
@@ -309,6 +330,7 @@ def main(argv=None) -> int:
             args.checkpoint_dir, args.phases, trainer.state.params,
             meta={"arch": cfg.name})
         print(f"checkpoint: {path}")
+    restore_handlers(previous_handlers)
     return 0
 
 

@@ -201,19 +201,26 @@ def paged_arch_unsupported(cfg: ModelConfig) -> Optional[str]:
     return None
 
 
+POOL_LANES = 128
+
+
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                      dtype=jnp.float32) -> Dict:
     """Allocate the pooled block KV cache shared by all requests.
 
-    Layout ``[L, KV, NB, BS, Dh]`` (kv-head major within a layer) so the
-    paged-attention kernel streams one ``[BS, Dh]`` tile per page visit.
-    Ownership of pages lives host-side in ``repro.serve.paged_cache``.
+    Layout ``[L, KV, NB, BS, lanes]`` (kv-head major within a layer) so
+    the paged-attention kernel streams one ``[BS, lanes]`` tile per page
+    visit.  ``lanes`` is ``head_dim`` rounded up to the TPU's 128-lane
+    width: the chip stores a narrower trailing dim padded to 128 anyway,
+    and its DMA engine moves only lane-aligned rows, which the in-place
+    row write needs.  Rows are zero-padded on the way in.  Ownership of
+    pages lives host-side in ``repro.serve.paged_cache``.
     """
     reason = paged_arch_unsupported(cfg)
     if reason is not None:
         raise ValueError(f"{cfg.name}: paged decode unsupported: {reason}")
     shape = (cfg.n_layers, cfg.n_kv_heads, num_blocks, block_size,
-             cfg.head_dim)
+             -(-cfg.head_dim // POOL_LANES) * POOL_LANES)
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}
 
@@ -248,6 +255,24 @@ def _paged_qkv(cfg: ModelConfig, lp: Dict, x: jax.Array,
     q = apply_rope(q, positions, cfg.rope_theta)
     k_new = apply_rope(k_new, positions, cfg.rope_theta)
     return q, k_new, v_new
+
+
+def _write_rows(k_pages, v_pages, k_new, v_new, page_idx, offset, write_ok,
+                *, layer, kernel_mode, mesh, slot_shard):
+    """Write a ``[B, T]`` chunk of K/V rows into layer ``layer`` with one
+    row-write dispatch: the chunk is flattened to ``B * T`` slots (row
+    ``(b, t)`` keeps slot ``b``'s home shard under a mesh)."""
+    from repro.kernels import ops as kops
+
+    b, t = page_idx.shape
+    if slot_shard is not None:
+        slot_shard = jnp.repeat(slot_shard, t)
+    return kops.paged_kv_write(
+        k_pages, v_pages,
+        k_new.reshape((b * t,) + k_new.shape[2:]),
+        v_new.reshape((b * t,) + v_new.shape[2:]),
+        page_idx.reshape(-1), offset.reshape(-1), write_ok.reshape(-1),
+        layer=layer, mode=kernel_mode, mesh=mesh, slot_shard=slot_shard)
 
 
 def _paged_head_full(params: Dict, cfg: ModelConfig, x: jax.Array
@@ -411,13 +436,10 @@ def decode_step_paged_multi(
     for layer in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[layer], params["layers"])
         q, k_new, v_new = _paged_qkv(cfg, lp, x, positions)
-        for step in range(t):
-            k_pages, v_pages = kops.paged_kv_write(
-                k_pages, v_pages, k_new[:, step], v_new[:, step],
-                page_idx[:, step], offset[:, step], write_ok[:, step],
-                layer=layer, mode=kernel_mode,
-                mesh=mesh, slot_shard=slot_shard,
-            )
+        k_pages, v_pages = _write_rows(
+            k_pages, v_pages, k_new, v_new, page_idx, offset, write_ok,
+            layer=layer, kernel_mode=kernel_mode, mesh=mesh,
+            slot_shard=slot_shard)
         attn_out = kops.paged_attention_multi(
             q, k_pages[layer], v_pages[layer], block_tables,
             context_lens, window=cfg.window_for_layer(layer),
@@ -478,13 +500,10 @@ def decode_step_paged_varlen(
     for layer in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[layer], params["layers"])
         q, k_new, v_new = _paged_qkv(cfg, lp, x, positions)
-        for step in range(t):
-            k_pages, v_pages = kops.paged_kv_write(
-                k_pages, v_pages, k_new[:, step], v_new[:, step],
-                page_idx[:, step], offset[:, step], write_ok[:, step],
-                layer=layer, mode=kernel_mode,
-                mesh=mesh, slot_shard=slot_shard,
-            )
+        k_pages, v_pages = _write_rows(
+            k_pages, v_pages, k_new, v_new, page_idx, offset, write_ok,
+            layer=layer, kernel_mode=kernel_mode, mesh=mesh,
+            slot_shard=slot_shard)
         attn_out = kops.paged_attention_varlen(
             q, k_pages[layer], v_pages[layer], block_tables,
             safe_start, row_len, window=cfg.window_for_layer(layer),
@@ -521,6 +540,7 @@ def decode_step_paged_carried(
     sliding-window archs) and no mesh dispatch.
     """
     from repro.kernels import ops as kops
+    from repro.kernels.ref import pad_lanes
 
     if cfg.sliding_window is not None:
         raise ValueError(
@@ -549,8 +569,9 @@ def decode_step_paged_carried(
         lp, k_pages, v_pages = xs
         q, k_new, v_new = _paged_qkv(cfg, lp, x, safe_pos[:, None])
         # [B, 1, KV, Dh] -> [KV, B, Dh] rows, scattered per slot.
-        k_rows = k_new[:, 0].transpose(1, 0, 2)
-        v_rows = v_new[:, 0].transpose(1, 0, 2)
+        lanes = k_pages.shape[-1]
+        k_rows = pad_lanes(k_new[:, 0], lanes).transpose(1, 0, 2)
+        v_rows = pad_lanes(v_new[:, 0], lanes).transpose(1, 0, 2)
         k_pages = k_pages.at[:, page_idx, offset, :].set(
             k_rows.astype(k_pages.dtype), mode="drop")
         v_pages = v_pages.at[:, page_idx, offset, :].set(
@@ -592,13 +613,17 @@ def write_prefill_to_pages(
     p = cache_k.shape[2]
     n_tiles = -(-p // block_size)
     pad = n_tiles * block_size - p
-    # [L, 1, P, KV, Dh] -> [L, KV, P(+pad), Dh]
-    k_rows = cache_k[:, 0].transpose(0, 2, 1, 3).astype(k_pages.dtype)
-    v_rows = cache_v[:, 0].transpose(0, 2, 1, 3).astype(v_pages.dtype)
+    from repro.kernels.ref import masked_inplace_update, pad_lanes
+
+    # [L, 1, P, KV, Dh] -> [L, KV, P(+pad), lanes]
+    lanes = k_pages.shape[4]
+    k_rows = pad_lanes(cache_k[:, 0].transpose(0, 2, 1, 3), lanes)
+    v_rows = pad_lanes(cache_v[:, 0].transpose(0, 2, 1, 3), lanes)
+    k_rows = k_rows.astype(k_pages.dtype)
+    v_rows = v_rows.astype(v_pages.dtype)
     if pad:
         k_rows = jnp.pad(k_rows, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v_rows = jnp.pad(v_rows, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    from repro.kernels.ref import masked_inplace_update
 
     zero = jnp.zeros((), jnp.int32)
     for j in range(n_tiles):
@@ -648,7 +673,6 @@ def write_prefill_batch_to_pages(
     if not _sharded(mesh, axis_name):
         return write_all(cache_k, cache_v, pages, blocks, prompt_lens)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(kc, vc, k_pages, v_pages, blocks, plens, home):
@@ -659,10 +683,10 @@ def write_prefill_batch_to_pages(
         return out["k_pages"], out["v_pages"]
 
     pool = P(None, None, axis_name, None, None)
-    k_pages, v_pages = shard_map(
+    k_pages, v_pages = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), pool, pool, P(), P(), P()),
-        out_specs=(pool, pool), check_rep=False,
+        out_specs=(pool, pool), check_vma=False,
     )(cache_k, cache_v, pages["k_pages"], pages["v_pages"],
       blocks, prompt_lens, home_shard.astype(jnp.int32))
     return {"k_pages": k_pages, "v_pages": v_pages}
@@ -718,7 +742,6 @@ def copy_page_rows(
             pages["k_pages"], pages["v_pages"], src, dst, rows)
         return {"k_pages": k_pages, "v_pages": v_pages}
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(k_pages, v_pages, src, dst, rows, home):
@@ -727,10 +750,10 @@ def copy_page_rows(
         return copy_all(k_pages, v_pages, src, dst, local_rows)
 
     pool = P(None, None, axis_name, None, None)
-    k_pages, v_pages = shard_map(
+    k_pages, v_pages = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pool, pool, P(), P(), P(), P()),
-        out_specs=(pool, pool), check_rep=False,
+        out_specs=(pool, pool), check_vma=False,
     )(pages["k_pages"], pages["v_pages"], src, dst, rows,
       home_shard.astype(jnp.int32))
     return {"k_pages": k_pages, "v_pages": v_pages}

@@ -16,7 +16,7 @@ from repro.resilience.faults import (
     NULL_INJECTOR,
     parse_fault_plan,
 )
-from repro.resilience.signals import install_flush_handlers
+from repro.resilience.signals import install_flush_handlers, restore_handlers
 from repro.resilience.supervision import (
     BackoffPolicy,
     Heartbeat,
@@ -34,6 +34,7 @@ __all__ = [
     "InjectedFault",
     "NULL_INJECTOR",
     "install_flush_handlers",
+    "restore_handlers",
     "RestartContext",
     "SupervisionError",
     "parse_fault_plan",

@@ -35,3 +35,14 @@ def install_flush_handlers(
     for sig in signals:
         previous[sig] = signal.signal(sig, _handler)
     return previous
+
+
+def restore_handlers(previous: Dict[int, object]) -> None:
+    """Put back the handlers :func:`install_flush_handlers` replaced.
+
+    A launcher calls this when its ``main()`` finishes, so the flush
+    closure — and the engine or trainer it reaches — does not outlive
+    the run in a process that goes on (one that calls several
+    launchers in turn)."""
+    for sig, prev in previous.items():
+        signal.signal(sig, prev)

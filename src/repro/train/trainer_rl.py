@@ -102,8 +102,8 @@ def _phase_advantages(hp: RLHyperparams, params, batch: RolloutBatch):
     if hp.algorithm == "vaco" and hp.realign:
         log_pi_T, _ = _log_pi_and_entropy(params, batch.obs, batch.actions)
         log_ratios = log_pi_T - batch.log_beta
-        # kernels.ops dispatches reference (CPU/autodiff) vs the Pallas
-        # TPU kernel per REPRO_KERNEL_MODE; realignment is once-per-phase
+        # kernels.ops dispatches the jnp reference (CPU) or the Pallas
+        # kernel (TPU) by platform; realignment is once-per-phase
         # and consumed under stop_gradient, so the no-autodiff kernel
         # path is safe here.
         vs, advantages = kops.vtrace(

@@ -87,6 +87,7 @@ class RLVRHyperparams:
     warmup_steps: int = 300       # supervised base-model creation
     warmup_lr: float = 3e-3
     warmup_batch: int = 64
+    eval_prompts: int = 256       # greedy-accuracy eval set size
     # --- runtime ---
     runtime: str = "forward_n"    # forward_n | threaded
     store_capacity: int = 4       # policy snapshot ring size
@@ -140,18 +141,20 @@ class RLVRTrainState(NamedTuple):
     updates: jax.Array
 
 
-def make_update_step(bundle: ModelBundle, hp: RLVRHyperparams,
-                     prompt_len: int):
+def _make_grad_fn(bundle: ModelBundle, hp: RLVRHyperparams,
+                  prompt_len: int):
+    """``value_and_grad`` of the GRPO/VACO token loss (with aux)."""
     grpo_cfg = GRPOConfig(
         clip_low=hp.clip_low, clip_high=hp.clip_high,
         use_vaco=(hp.algorithm == "grpo_vaco"), delta=hp.delta,
         entropy_coef=hp.entropy_coef,
     )
-    opt_cfg = AdamWConfig(lr=hp.lr, weight_decay=hp.weight_decay, eps=1e-8)
 
     def loss_fn(params, tokens, log_beta, mask, advantages):
+        # The fused log-prob kernel is forward-only, so the differentiated
+        # loss scores through the jnp path on every platform.
         log_pi, entropy, _ = score_tokens(
-            bundle, params, tokens, prompt_len)
+            bundle, params, tokens, prompt_len, kernel_mode="reference")
         loss, aux = grpo_token_loss(
             log_pi=log_pi, log_beta=log_beta, advantages=advantages,
             token_mask=mask, cfg=grpo_cfg,
@@ -160,7 +163,13 @@ def make_update_step(bundle: ModelBundle, hp: RLVRHyperparams,
             jnp.sum(mask), 1.0)
         return loss, aux
 
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    return jax.value_and_grad(loss_fn, has_aux=True)
+
+
+def make_update_step(bundle: ModelBundle, hp: RLVRHyperparams,
+                     prompt_len: int):
+    opt_cfg = AdamWConfig(lr=hp.lr, weight_decay=hp.weight_decay, eps=1e-8)
+    grad_fn = _make_grad_fn(bundle, hp, prompt_len)
 
     @jax.jit
     def update(state: RLVRTrainState, tokens, log_beta, mask, advantages):
@@ -182,25 +191,8 @@ def make_split_update_step(bundle: ModelBundle, hp: RLVRHyperparams,
     gradients so the controller can inspect/rescale them on the host,
     ``apply_step`` then clips and applies.  Same math as
     :func:`make_update_step`, two dispatches instead of one."""
-    grpo_cfg = GRPOConfig(
-        clip_low=hp.clip_low, clip_high=hp.clip_high,
-        use_vaco=(hp.algorithm == "grpo_vaco"), delta=hp.delta,
-        entropy_coef=hp.entropy_coef,
-    )
     opt_cfg = AdamWConfig(lr=hp.lr, weight_decay=hp.weight_decay, eps=1e-8)
-
-    def loss_fn(params, tokens, log_beta, mask, advantages):
-        log_pi, entropy, _ = score_tokens(
-            bundle, params, tokens, prompt_len)
-        loss, aux = grpo_token_loss(
-            log_pi=log_pi, log_beta=log_beta, advantages=advantages,
-            token_mask=mask, cfg=grpo_cfg,
-        )
-        aux["token_entropy"] = jnp.sum(entropy * mask) / jnp.maximum(
-            jnp.sum(mask), 1.0)
-        return loss, aux
-
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    grad_fn = _make_grad_fn(bundle, hp, prompt_len)
 
     @jax.jit
     def grad_step(state: RLVRTrainState, tokens, log_beta, mask,
@@ -608,8 +600,9 @@ class RLVRTrainer:
             ))
         return logs
 
-    def evaluate(self, n: Optional[int] = 256) -> float:
-        return self.generator.eval_accuracy(self.state.params, n)
+    def evaluate(self, n: Optional[int] = None) -> float:
+        return self.generator.eval_accuracy(
+            self.state.params, n if n is not None else self.hp.eval_prompts)
 
     def train(self, phases: int, eval_every: int = 5) -> RLVRResult:
         accs: List[float] = []

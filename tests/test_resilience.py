@@ -21,6 +21,7 @@ from repro.resilience import (
     RestartContext,
     SupervisionError,
     install_flush_handlers,
+    restore_handlers,
     parse_fault_plan,
     supervise,
     tree_all_finite,
@@ -428,8 +429,7 @@ def test_install_flush_handlers_one_shot():
         # one-shot: the previous disposition is already back
         assert signal.getsignal(signal.SIGTERM) is prev[signal.SIGTERM]
     finally:
-        for sig, handler in prev.items():
-            signal.signal(sig, handler)
+        restore_handlers(prev)
 
 
 # --- resilience stats plumbing ----------------------------------------------

@@ -324,7 +324,8 @@ def test_launcher_serves_sharded(capsys):
     """--mesh data=2 end to end through the CLI (versioned runtime)."""
     from repro.launch.serve import main
 
-    rc = main(["--engine", "continuous", "--mesh", "data=2",
+    rc = main(["--arch", "qwen2.5-0.5b-reduced",
+               "--engine", "continuous", "--mesh", "data=2",
                "--requests", "4", "--mixed-lengths", "2,4",
                "--max-batch", "2", "--runtime", "versioned"])
     assert rc == 0
