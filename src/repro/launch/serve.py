@@ -356,9 +356,11 @@ def main(argv=None) -> int:
                          "full: adds a per-emitted-token instant with "
                          "version/lag provenance")
     ap.add_argument("--profiler-annotations", action="store_true",
-                    help="wrap engine dispatches in jax.profiler."
-                         "TraceAnnotation (names show up on the device "
-                         "timeline of a jax.profiler.trace() capture)")
+                    help="mirror the engine's spans (serve.step, "
+                         "serve.schedule, serve.decode, ...) into jax."
+                         "profiler.TraceAnnotation, so a jax.profiler "
+                         "capture shows each phase of a round beside "
+                         "the device's work")
     ap.add_argument("--swap-interval", type=int, default=1)
     ap.add_argument("--temperature", type=float, default=1.0)
     ap.add_argument("--top-p", type=float, default=1.0)

@@ -6,13 +6,15 @@ package makes that visible on a live run:
 
 * ``tracer``   — ring-buffered span/instant/counter collector with
                  monotonic clocks; ``NULL_TRACER`` makes every
-                 instrumentation point free when tracing is off.
+                 instrumentation point free when tracing is off, and
+                 ``mirrored`` puts its sync spans into ``jax.profiler``
+                 captures as ``TraceAnnotation``s, beside the device's
+                 work.
 * ``registry`` — one ``MetricsRegistry`` that ``ServeStats``,
                  ``RuntimeQueueStats`` and the trainers register into;
                  one ``snapshot()`` feeds telemetry, launchers and
                  benchmarks alike.
-* ``perfetto`` — Chrome/Perfetto ``trace_event`` JSON + JSONL export,
-                 and optional ``jax.profiler`` trace annotations.
+* ``perfetto`` — Chrome/Perfetto ``trace_event`` JSON + JSONL export.
 
 ``benchmarks/trace_report.py`` turns an exported trace into the
 lag-attribution report (time-in-state per request, lag-at-emission
@@ -23,7 +25,6 @@ from repro.obs.perfetto import (
     export_perfetto,
     export_trace_jsonl,
     load_trace_events,
-    trace_annotation,
 )
 from repro.obs.registry import (
     Counter,
@@ -33,10 +34,12 @@ from repro.obs.registry import (
 )
 from repro.obs.tracer import (
     NULL_TRACER,
+    ProfilerTracer,
     Span,
     TraceEvent,
     Tracer,
     make_tracer,
+    mirrored,
 )
 
 __all__ = [
@@ -45,6 +48,7 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "NULL_TRACER",
+    "ProfilerTracer",
     "Span",
     "TraceEvent",
     "Tracer",
@@ -53,5 +57,5 @@ __all__ = [
     "export_trace_jsonl",
     "load_trace_events",
     "make_tracer",
-    "trace_annotation",
+    "mirrored",
 ]

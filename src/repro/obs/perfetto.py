@@ -10,15 +10,9 @@ queue depth, live policy lag).
 
 ``export_trace_jsonl`` is the grep-able flat form (one event per
 line); ``benchmarks/trace_report.py`` reads either.
-
-``trace_annotation`` wraps ``jax.profiler.TraceAnnotation`` when the
-installed jax has it — so a ``jax.profiler.trace()`` capture taken
-around a serve run shows the engine's dispatch names on the device
-timeline — and degrades to a no-op context otherwise.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Union
 
@@ -29,7 +23,6 @@ __all__ = [
     "export_perfetto",
     "export_trace_jsonl",
     "load_trace_events",
-    "trace_annotation",
 ]
 
 # Async spans need a category for id-scoping in the trace_event spec.
@@ -150,18 +143,3 @@ def load_trace_events(path: str) -> List[Dict[str, Any]]:
         events.append(rec)
     return events
 
-
-@contextlib.contextmanager
-def trace_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` when available, else no-op."""
-    ann = None
-    try:
-        import jax.profiler as _prof
-        ann = getattr(_prof, "TraceAnnotation", None)
-    except Exception:
-        ann = None
-    if ann is None:
-        yield
-        return
-    with ann(name):
-        yield

@@ -19,6 +19,15 @@ Design constraints, in order:
    async spans (keyed by an id) model per-request lifecycle states
    that overlap arbitrarily across requests, ``i`` instants, ``C``
    counter samples.  ``obs.perfetto`` serializes them 1:1.
+4. **On the device trace's clock when asked.**  A tracer passed
+   through :func:`mirrored` also opens a
+   ``jax.profiler.TraceAnnotation`` named ``f"{pid}.{name}"`` around
+   every sync span opened with :meth:`Tracer.span` — ``serve.step``,
+   ``serve.decode`` — so a ``jax.profiler`` capture shows the same
+   spans beside the device's work.  The annotation carries the name
+   only, never the span's args.  Instants and counters stay in the
+   ring.  With no tracer to mirror, :class:`ProfilerTracer` opens the
+   annotations and keeps no ring.
 
 Tracks are ``(pid, tid)`` *string* pairs — e.g. ``("serve",
 "slot0")``, ``("runtime", "producer")`` — mapped to integer ids at
@@ -42,10 +51,12 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 __all__ = [
     "NULL_TRACER",
+    "ProfilerTracer",
     "Span",
     "TraceEvent",
     "Tracer",
     "make_tracer",
+    "mirrored",
 ]
 
 DETAIL_LEVELS = ("off", "spans", "full")
@@ -70,22 +81,36 @@ class TraceEvent:
 
 
 class Span:
-    """Context manager closing a sync span on exit (exceptions too)."""
+    """Context manager closing a sync span on exit (exceptions too),
+    and the profiler annotation that mirrors it, where there is one."""
 
-    __slots__ = ("_tracer", "_name", "_pid", "_tid")
+    __slots__ = ("_tracer", "_name", "_pid", "_tid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, pid: str,
-                 tid: str) -> None:
+                 tid: str, ann: Any = None) -> None:
         self._tracer = tracer
         self._name = name
         self._pid = pid
         self._tid = tid
+        self._ann = ann
 
     def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._tracer.end(self._name, self._pid, self._tid)
+
+
+def _trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation``, imported when first mirrored:
+    a tracer that never mirrors never imports jax."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
 
 
 class Tracer:
@@ -112,6 +137,7 @@ class Tracer:
         self._epoch_ns = time.monotonic_ns()
         self._dropped = 0
         self._lock = threading.Lock()   # only for clear()/drain races
+        self._mirror: Any = None     # TraceAnnotation, set by mirrored()
 
     # -- clocks ---------------------------------------------------------------
 
@@ -119,6 +145,11 @@ class Tracer:
     def full(self) -> bool:
         """True when per-token events should be emitted."""
         return self.detail == "full"
+
+    @property
+    def mirror(self) -> bool:
+        """True when sync spans also open profiler annotations."""
+        return self._mirror is not None
 
     def now(self) -> int:
         """ns since the tracer epoch (monotonic)."""
@@ -152,7 +183,9 @@ class Tracer:
              tid: str = "engine", **args: Any) -> Span:
         """``with tracer.span("decode", tid="engine"): ...``"""
         self.begin(name, pid, tid, **args)
-        return Span(self, name, pid, tid)
+        mirror = self._mirror
+        return Span(self, name, pid, tid,
+                    None if mirror is None else mirror(f"{pid}.{name}"))
 
     def async_begin(self, name: str, aid: int, pid: str = "serve",
                     tid: str = "requests", ts: Optional[int] = None,
@@ -237,6 +270,7 @@ class _NullTracer(Tracer):
         self._dropped = 0
         self._epoch_ns = 0
         self._lock = threading.Lock()
+        self._mirror = None
 
     @property
     def full(self) -> bool:
@@ -265,6 +299,32 @@ class _NullTracer(Tracer):
 
 
 NULL_TRACER = _NullTracer()
+
+
+class ProfilerTracer(_NullTracer):
+    """Sync spans as ``jax.profiler`` annotations only: no ring, no
+    instants, no counters (``enabled`` is False, so call sites skip
+    them), one ``TraceAnnotation`` per :meth:`span`."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self._mirror = _trace_annotation()
+
+    def span(self, name: str, pid: str = "serve",  # type: ignore[override]
+             tid: str = "engine", **args: Any) -> Any:
+        return self._mirror(f"{pid}.{name}")
+
+
+def mirrored(tracer: Optional[Tracer]) -> Tracer:
+    """The tracer to use when sync spans should also land in the
+    ``jax.profiler`` trace: a live ``tracer``, mirroring from now on,
+    or a :class:`ProfilerTracer` where there is none (``None`` or
+    :data:`NULL_TRACER`, which stays a no-op)."""
+    if tracer is None or tracer is NULL_TRACER:
+        return ProfilerTracer()
+    if tracer._mirror is None:
+        tracer._mirror = _trace_annotation()
+    return tracer
 
 
 def make_tracer(detail: str = "spans",

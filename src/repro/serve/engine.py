@@ -98,7 +98,6 @@ paying for long drafts; the chosen-k histogram lands in
 """
 from __future__ import annotations
 
-import contextlib
 import time
 import warnings
 from dataclasses import dataclass
@@ -113,9 +112,8 @@ from repro.distributed.sharding import replicated, shard_paged_pool
 from repro.kernels.ops import mesh_data_size
 from repro.metrics.runtime_metrics import LagHistogram, collect_serve_stats
 from repro.models.registry import ModelBundle
-from repro.obs.perfetto import trace_annotation
 from repro.obs.registry import MetricsRegistry
-from repro.obs.tracer import NULL_TRACER, Tracer
+from repro.obs.tracer import NULL_TRACER, Tracer, mirrored
 from repro.models.transformer import (copy_page_rows,
                                       write_prefill_batch_to_pages)
 from repro.rollout.sampler import _top_p_filter, speculative_accept
@@ -244,6 +242,15 @@ class CallableDraft:
         self.fn = fn
 
 
+def _jit(fn: Callable, name: Optional[str] = None, **kw) -> Callable:
+    """``jax.jit`` under a stable program name (``fn``'s own, or
+    ``name``): a profiler capture's XLA Modules line shows
+    ``jit_<name>`` for each program the engine dispatches."""
+    if name is not None:
+        fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **kw)
+
+
 class ServeEngine:
     """Paged-KV continuous-batching generation over a ModelBundle."""
 
@@ -312,13 +319,19 @@ class ServeEngine:
         window back to the pool.
 
         ``tracer`` (an ``obs.Tracer``; default: the zero-cost
-        ``NULL_TRACER``) records the request lifecycle and dispatch
-        spans; ``metrics`` (an ``obs.MetricsRegistry``; default: a
-        fresh one) receives the engine's serve-time histograms (TTFT,
-        inter-token, queue-wait, request latency) and the ``"serve"``
-        snapshot producer.  ``annotate=True`` wraps jitted dispatches
-        in ``jax.profiler`` trace annotations so device-side profiler
-        captures show the engine's phase names.
+        ``NULL_TRACER``) records the request lifecycle and the spans of
+        each round (``step`` enclosing ``schedule``, then ``decode``,
+        ``chunked_round``, ``prefill``, ``suffix_prefill``, ``draft`` or
+        ``verify``, each split into ``upload``, ``dispatch``,
+        ``result_wait`` and ``record``); ``metrics`` (an
+        ``obs.MetricsRegistry``; default: a fresh one) receives the
+        engine's serve-time histograms (TTFT, inter-token, queue-wait,
+        request latency) and the ``"serve"`` snapshot producer.
+        ``annotate=True`` mirrors those spans into ``jax.profiler`` as
+        ``serve.<name>`` annotations (``obs.mirrored``: the tracer's
+        own, or annotations alone when there is no tracer), so a
+        profiler capture shows each phase of a round beside the
+        device's work.
         """
         if bundle.decode_step_paged is None:
             from repro.models.transformer import paged_arch_unsupported
@@ -329,6 +342,8 @@ class ServeEngine:
             raise ValueError("need params or a PolicyStore")
         self.bundle = bundle
         self.store = store
+        if annotate:
+            tracer = mirrored(tracer)
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.metrics.register_producer(
@@ -349,8 +364,6 @@ class ServeEngine:
         self._h_latency = self.metrics.histogram("serve_request_latency_s")
         self._h_swap_stale = self.metrics.histogram("serve_swap_to_stale_s")
         self._swap_mono: Optional[float] = None   # last in-flight swap
-        self._ann = (trace_annotation if annotate
-                     else (lambda name: contextlib.nullcontext()))
         # 0 = never poll the store: weights move only by direct
         # params/version assignment (the serve-backed trainer's
         # forced-lag producer pins snapshots this way).
@@ -428,8 +441,8 @@ class ServeEngine:
         chunk = max(int(decode_chunk), 1)
         self.decode_chunk = chunk
 
-        def _decode(params, token, pages, tables, pos, active, remaining,
-                    slot_shard, key):
+        def serve_decode(params, token, pages, tables, pos, active,
+                         remaining, slot_shard, key):
             """`chunk` decode steps in one dispatch (lax.scan).
 
             Multi-step decode amortizes the per-step host round-trip —
@@ -467,7 +480,7 @@ class ServeEngine:
         # loop + kernels.ops.paged_kv_write), so the pool is updated
         # in place end to end: per-chunk cost is O(rows written), flat
         # in num_blocks (bench_serve --sweep-blocks measures it).
-        self._decode = jax.jit(_decode, donate_argnums=(2,))
+        self._decode = _jit(serve_decode, donate_argnums=(2,))
         # Prefill dispatches are keyed by (padded length, group size):
         # batched prefill stacks same-padded-length admissions into one
         # forward, so bursty admissions stop paying a dispatch each.
@@ -683,8 +696,8 @@ class ServeEngine:
         kernel_mode = self._kernel_mode
         mesh = self.mesh
 
-        def _draft(params, token, pages, tables, pos, active, cap,
-                   slot_shard, key):
+        def serve_draft(params, token, pages, tables, pos, active, cap,
+                        slot_shard, key):
             def body(carry, k_t):
                 token, pos, pages = carry
                 # Past-allocation steps go inactive: their write would
@@ -704,7 +717,7 @@ class ServeEngine:
                 body, (token, pos, pages), keys)
             return toks.T, logits.transpose(1, 0, 2), pages
 
-        return jax.jit(_draft, donate_argnums=(2,))
+        return _jit(serve_draft, donate_argnums=(2,))
 
     def _make_verify_fn(self, k: int):
         """Single-dispatch multi-token verify + accept + pos arithmetic."""
@@ -713,8 +726,8 @@ class ServeEngine:
         mesh = self.mesh
         temp, top_p = self._temperature, self._top_p
 
-        def _verify(params, first_tok, draft_toks, draft_logits, pages,
-                    tables, pos, active, cap, slot_shard, key):
+        def serve_verify(params, first_tok, draft_toks, draft_logits,
+                         pages, tables, pos, active, cap, slot_shard, key):
             # Queries = [t0, d1..d_{k-1}]: logits after query i score
             # draft token d_{i+1}.  All k rows are written; a rejection
             # just rewinds pos and the next chunk overwrites them.
@@ -732,7 +745,7 @@ class ServeEngine:
             n_emit = jnp.where(active, n_emit, 0)
             return toks, lps, n_acc, n_emit, pages
 
-        return jax.jit(_verify, donate_argnums=(4,))
+        return _jit(serve_verify, donate_argnums=(4,))
 
     # -- prefill (batched admissions) ----------------------------------------
 
@@ -796,11 +809,11 @@ class ServeEngine:
         if fn is None:
             mesh = self.mesh
 
-            def _cow(pages, src, dst, rows, home):
+            def serve_cow(pages, src, dst, rows, home):
                 return copy_page_rows(pages, src, dst, rows, home,
                                       mesh=mesh)
 
-            fn = self._cow_fns[n] = jax.jit(_cow, donate_argnums=(0,))
+            fn = self._cow_fns[n] = _jit(serve_cow, donate_argnums=(0,))
         return fn
 
     def _suffix_group(self, items: List,
@@ -849,40 +862,46 @@ class ServeEngine:
         fn = self._suffix_fns.get(key)
         if fn is None:
             fn = self._suffix_fns[key] = self._make_suffix()
-        toks_d = jnp.asarray(toks)
-        tables_d = jnp.asarray(tables)
-        pos_d = jnp.asarray(pos)
-        cap_d = jnp.asarray(cap)
-        home_d = jnp.asarray(home)
-        tlast = jnp.full((n,), t - 1, jnp.int32)
-        with self.tracer.span("suffix_prefill", tid="engine", n=n,
-                              suffix=t), \
-                self._ann("serve.suffix_prefill"):
-            tok, lp, self.pages = fn(
-                self.params, toks_d, self.pages, tables_d, pos_d, cap_d,
-                home_d, tlast, self._next_key())
-        self.stats.prefills += n
-        self.stats.prefill_dispatches += 1
-        self.stats.prefill_tokens += n * t
+        dfn = None
         if isinstance(self.draft, ModelDraft):
             dfn = self._draft_suffix_fns.get(key)
             if dfn is None:
                 dfn = self._draft_suffix_fns[key] = \
                     self._make_suffix(draft=True)
-            self.draft.pages = dfn(
-                self.draft.params, toks_d, self.draft.pages, tables_d,
-                pos_d, cap_d, home_d)
-        tok_np, lp_np = np.asarray(tok), np.asarray(lp)
-        for i, (req, ids, plen) in enumerate(items):
-            slot = req.slot
-            self._tables[slot] = tables[i]
-            self._pos[slot] = plen
-            req.num_prefilled = plen
-            if req.tokens:                     # resume after preemption
-                self._last_tok[slot] = req.tokens[-1]
-            else:
-                self._record(req, int(tok_np[i]), float(lp_np[i]),
-                             finished)
+        tr = self.tracer
+        with tr.span("suffix_prefill", tid="engine", n=n, suffix=t):
+            with tr.span("upload", tid="engine"):
+                toks_d = jnp.asarray(toks)
+                tables_d = jnp.asarray(tables)
+                pos_d = jnp.asarray(pos)
+                cap_d = jnp.asarray(cap)
+                home_d = jnp.asarray(home)
+                tlast = jnp.full((n,), t - 1, jnp.int32)
+                rng = self._next_key()
+            with tr.span("dispatch", tid="engine"):
+                tok, lp, self.pages = fn(
+                    self.params, toks_d, self.pages, tables_d, pos_d,
+                    cap_d, home_d, tlast, rng)
+                if dfn is not None:
+                    self.draft.pages = dfn(
+                        self.draft.params, toks_d, self.draft.pages,
+                        tables_d, pos_d, cap_d, home_d)
+            with tr.span("result_wait", tid="engine"):
+                tok_np, lp_np = np.asarray(tok), np.asarray(lp)
+            with tr.span("record", tid="engine"):
+                self.stats.prefills += n
+                self.stats.prefill_dispatches += 1
+                self.stats.prefill_tokens += n * t
+                for i, (req, ids, plen) in enumerate(items):
+                    slot = req.slot
+                    self._tables[slot] = tables[i]
+                    self._pos[slot] = plen
+                    req.num_prefilled = plen
+                    if req.tokens:             # resume after preemption
+                        self._last_tok[slot] = req.tokens[-1]
+                    else:
+                        self._record(req, int(tok_np[i]), float(lp_np[i]),
+                                     finished)
 
     def _make_suffix(self, draft: bool = False):
         """Suffix-only prefill: T unmatched tokens through the
@@ -894,8 +913,8 @@ class ServeEngine:
         kernel_mode = self._kernel_mode
         mesh = self.mesh
 
-        def _suffix(params, tokens, pages, tables, pos, cap, home,
-                    tlast=None, key=None):
+        def serve_suffix_prefill(params, tokens, pages, tables, pos, cap,
+                                 home, tlast=None, key=None):
             ones = jnp.ones((tokens.shape[0],), bool)
             out, pages = bundle.decode_step_paged_multi(
                 params, tokens, pages, tables, pos, ones, cap,
@@ -907,7 +926,8 @@ class ServeEngine:
             tok, lp = sample(last, key)
             return tok, lp, pages
 
-        return jax.jit(_suffix, donate_argnums=(2,))
+        return _jit(serve_suffix_prefill, donate_argnums=(2,),
+                    name="serve_draft_suffix_prefill" if draft else None)
 
     def _prefill_group(self, padded: int, items: List,
                        finished: List[ServedTrajectory]) -> None:
@@ -928,44 +948,49 @@ class ServeEngine:
         fn = self._prefill_fns.get(key)
         if fn is None:
             fn = self._prefill_fns[key] = self._make_prefill(padded, n)
-        with self.tracer.span("prefill", tid="engine", n=n,
-                              padded=padded), \
-                self._ann("serve.prefill"):
-            toks, lps, self.pages = fn(
-                self.params, jnp.asarray(rows), jnp.asarray(kv_valid),
-                jnp.asarray(tables), jnp.asarray(plens),
-                jnp.asarray(home), self.pages, self._next_key())
-        self.stats.prefills += n
-        self.stats.prefill_dispatches += 1
-        self.stats.prefill_tokens += int(plens.sum())
+        dfn = None
         if isinstance(self.draft, ModelDraft):
             dfn = self._draft_prefill_fns.get(key)
             if dfn is None:
                 dfn = self._draft_prefill_fns[key] = \
                     self._make_draft_prefill(padded, n)
-            self.draft.pages = dfn(
-                self.draft.params, jnp.asarray(rows), jnp.asarray(kv_valid),
-                jnp.asarray(tables), jnp.asarray(plens),
-                jnp.asarray(home), self.draft.pages)
-        toks_np, lps_np = np.asarray(toks), np.asarray(lps)
-        for i, (req, ids, plen) in enumerate(items):
-            slot = req.slot
-            self._tables[slot] = tables[i]
-            self._pos[slot] = plen
-            req.num_prefilled = plen
-            if req.tokens:                     # resume after preemption
-                self._last_tok[slot] = req.tokens[-1]
-            else:
-                self._record(req, int(toks_np[i]), float(lps_np[i]),
-                             finished)
+        tr = self.tracer
+        with tr.span("prefill", tid="engine", n=n, padded=padded):
+            with tr.span("upload", tid="engine"):
+                args = (jnp.asarray(rows), jnp.asarray(kv_valid),
+                        jnp.asarray(tables), jnp.asarray(plens),
+                        jnp.asarray(home))
+                rng = self._next_key()
+            with tr.span("dispatch", tid="engine"):
+                toks, lps, self.pages = fn(self.params, *args, self.pages,
+                                           rng)
+                if dfn is not None:
+                    self.draft.pages = dfn(self.draft.params, *args,
+                                           self.draft.pages)
+            with tr.span("result_wait", tid="engine"):
+                toks_np, lps_np = np.asarray(toks), np.asarray(lps)
+            with tr.span("record", tid="engine"):
+                self.stats.prefills += n
+                self.stats.prefill_dispatches += 1
+                self.stats.prefill_tokens += int(plens.sum())
+                for i, (req, ids, plen) in enumerate(items):
+                    slot = req.slot
+                    self._tables[slot] = tables[i]
+                    self._pos[slot] = plen
+                    req.num_prefilled = plen
+                    if req.tokens:             # resume after preemption
+                        self._last_tok[slot] = req.tokens[-1]
+                    else:
+                        self._record(req, int(toks_np[i]),
+                                     float(lps_np[i]), finished)
 
     def _make_prefill(self, padded_len: int, n: int):
         bundle = self.bundle
         sample = self._sample
         mesh = self.mesh
 
-        def _prefill(params, prompts, kv_valid, blocks, plens, home,
-                     pages, key):
+        def serve_prefill(params, prompts, kv_valid, blocks, plens, home,
+                          pages, key):
             out = bundle.forward(
                 params, prompts, return_cache=True,
                 cache_len=padded_len, kv_valid=kv_valid)
@@ -980,14 +1005,14 @@ class ServeEngine:
             tok, lp = sample(last, key)
             return tok, lp, pages
 
-        return jax.jit(_prefill, donate_argnums=(6,))
+        return _jit(serve_prefill, donate_argnums=(6,))
 
     def _make_draft_prefill(self, padded_len: int, n: int):
         bundle_d = self.draft.bundle
         mesh = self.mesh
 
-        def _prefill(params, prompts, kv_valid, blocks, plens, home,
-                     pages):
+        def serve_draft_prefill(params, prompts, kv_valid, blocks, plens,
+                                home, pages):
             out = bundle_d.forward(
                 params, prompts, return_cache=True,
                 cache_len=padded_len, kv_valid=kv_valid)
@@ -995,7 +1020,7 @@ class ServeEngine:
                 out.cache["k"], out.cache["v"], pages, blocks, plens,
                 home, mesh=mesh)
 
-        return jax.jit(_prefill, donate_argnums=(6,))
+        return _jit(serve_draft_prefill, donate_argnums=(6,))
 
     def _record(self, req: Request, tok: int, lp: float,
                 finished: List[ServedTrajectory]) -> None:
@@ -1116,8 +1141,8 @@ class ServeEngine:
         kernel_mode = self._kernel_mode
         mesh = self.mesh
 
-        def _fn(params, tokens, pages, tables, row_start, row_len, cap,
-                slot_shard, key=None):
+        def serve_varlen(params, tokens, pages, tables, row_start, row_len,
+                         cap, slot_shard, key=None):
             out, pages = bundle.decode_step_paged_varlen(
                 params, tokens, pages, tables, row_start, row_len, cap,
                 kernel_mode=kernel_mode, mesh=mesh, slot_shard=slot_shard)
@@ -1129,7 +1154,8 @@ class ServeEngine:
             tok, lp = sample(logits, key)
             return tok, lp, pages
 
-        return jax.jit(_fn, donate_argnums=(2,))
+        return _jit(serve_varlen, donate_argnums=(2,),
+                    name="serve_draft_varlen" if draft else None)
 
     def _chunked_round(self, finished: List[ServedTrajectory]) -> bool:
         """One unified varlen round, or False when no prefill is pending
@@ -1241,26 +1267,44 @@ class ServeEngine:
             cap[s] = len(r.blocks) * bs
         n_tile_tokens = sum(n for _, _, n in chunks)
         fn = self._varlen_fn(t_pad)
-        tokens_d = jnp.asarray(tokens)
-        rs_d = jnp.asarray(row_start)
-        rl_d = jnp.asarray(row_len)
-        cap_d = jnp.asarray(cap)
-        tables_d = self._dev("tables", self._tables)
-        shard_d = self._dev("slot_shard", self._slot_shard)
+        dfn = (self._varlen_fn(t_pad, draft=True)
+               if isinstance(self.draft, ModelDraft) else None)
         with tr.span("chunked_round", tid="engine",
                      decode=len(decode_reqs), tiles=len(chunks),
-                     tokens=int(row_len.sum())), \
-                self._ann("serve.chunked_round"):
-            tok, lp, self.pages = fn(
-                self.params, tokens_d, self.pages, tables_d,
-                rs_d, rl_d, cap_d, shard_d, self._next_key())
-        if isinstance(self.draft, ModelDraft):
-            # Mirror the same rows into the draft pool (draft weights):
-            # later speculative rounds read them as resident context.
-            self.draft.pages = self._varlen_fn(t_pad, draft=True)(
-                self.draft.params, tokens_d, self.draft.pages, tables_d,
-                rs_d, rl_d, cap_d, shard_d)
-        toks_np, lps_np = np.asarray(tok), np.asarray(lp)
+                     tokens=int(row_len.sum())):
+            with tr.span("upload", tid="engine"):
+                tokens_d = jnp.asarray(tokens)
+                rs_d = jnp.asarray(row_start)
+                rl_d = jnp.asarray(row_len)
+                cap_d = jnp.asarray(cap)
+                tables_d = self._dev("tables", self._tables)
+                shard_d = self._dev("slot_shard", self._slot_shard)
+                rng = self._next_key()
+            with tr.span("dispatch", tid="engine"):
+                tok, lp, self.pages = fn(
+                    self.params, tokens_d, self.pages, tables_d,
+                    rs_d, rl_d, cap_d, shard_d, rng)
+                if dfn is not None:
+                    # Mirror the same rows into the draft pool (draft
+                    # weights): later speculative rounds read them as
+                    # resident context.
+                    self.draft.pages = dfn(
+                        self.draft.params, tokens_d, self.draft.pages,
+                        tables_d, rs_d, rl_d, cap_d, shard_d)
+            with tr.span("result_wait", tid="engine"):
+                toks_np, lps_np = np.asarray(tok), np.asarray(lp)
+            with tr.span("record", tid="engine"):
+                self._record_chunked(chunks, decode_reqs, n_tile_tokens,
+                                     toks_np, lps_np, finished)
+        return True
+
+    def _record_chunked(self, chunks: List, decode_reqs: List[Request],
+                        n_tile_tokens: int, toks_np: np.ndarray,
+                        lps_np: np.ndarray,
+                        finished: List[ServedTrajectory]) -> None:
+        """Book a varlen round's results: tiles advance their prefill
+        cursor (a last tile emits the request's first token), decode
+        rows emit one token each."""
         self.stats.prefill_dispatches += 1
         self.stats.prefill_tokens += n_tile_tokens
         if decode_reqs:
@@ -1289,7 +1333,6 @@ class ServeEngine:
             self._pos[slot] += 1
             self._record(r, int(toks_np[slot]), float(lps_np[slot]),
                          finished)
-        return True
 
     # -- the decode loop -----------------------------------------------------
 
@@ -1297,6 +1340,48 @@ class ServeEngine:
         """One scheduling round + decode chunk (or speculative round);
         returns newly finished trajectories."""
         finished: List[ServedTrajectory] = []
+        tr = self.tracer
+        with tr.span("step", tid="engine"):
+            with tr.span("schedule", tid="engine"):
+                remaining = self._schedule(finished)
+            if self.chunked_prefill and self._chunked_round(finished):
+                # A unified varlen round ran (prefill tiles + one decode
+                # token per eligible slot); speculation and the
+                # multi-step decode chunk resume once no prefill is
+                # pending.
+                return finished
+            if not self._active.any():
+                return finished
+            if self._spec_k_active:
+                with tr.span("spec_round", tid="engine"):
+                    self._spec_round(finished)
+                return finished
+            with tr.span("decode", tid="engine", chunk=self.decode_chunk):
+                with tr.span("upload", tid="engine"):
+                    token = jnp.asarray(self._last_tok)
+                    args = (self._dev("tables", self._tables),
+                            jnp.asarray(self._pos),
+                            self._dev("active", self._active),
+                            self._dev("remaining", remaining),
+                            self._dev("slot_shard", self._slot_shard),
+                            self._next_key())
+                with tr.span("dispatch", tid="engine"):
+                    toks, lps, masks, self.pages = self._decode(
+                        self.params, token, self.pages, *args)
+                with tr.span("result_wait", tid="engine"):
+                    toks_np = np.asarray(toks)       # [chunk, B]
+                    lps_np = np.asarray(lps)
+                    masks_np = np.asarray(masks)
+                with tr.span("record", tid="engine"):
+                    self._record_decode(toks_np, lps_np, masks_np,
+                                        finished)
+        return finished
+
+    def _schedule(self, finished: List[ServedTrajectory]) -> np.ndarray:
+        """The round's host-side scheduling: swap, deadline sweep,
+        admissions (and, off the chunked path, their prefill), the
+        slot-state rebuild and the counter samples.  Returns each
+        slot's remaining token budget."""
         tr = self.tracer
         self._maybe_swap()
         self.stats.steps += 1
@@ -1316,8 +1401,7 @@ class ServeEngine:
         for req in self.scheduler.expire():
             self._timeout_finish(req, finished)
         lookahead = self._spec_k_active or self.decode_chunk
-        with tr.span("schedule", tid="engine"):
-            admitted, _ = self.scheduler.schedule(lookahead=lookahead)
+        admitted, _ = self.scheduler.schedule(lookahead=lookahead)
         self.stats.preemptions = self.scheduler.preemptions
         if admitted:
             now = time.monotonic()
@@ -1373,29 +1457,13 @@ class ServeEngine:
             if self.store is not None:
                 tr.counter("policy_lag",
                            lag=float(self.store.version - self.version))
-        if self.chunked_prefill and self._chunked_round(finished):
-            # A unified varlen round ran (prefill tiles + one decode
-            # token per eligible slot); speculation and the multi-step
-            # decode chunk resume once no prefill is pending.
-            return finished
-        if not self._active.any():
-            return finished
-        if self._spec_k_active:
-            with tr.span("spec_round", tid="engine"):
-                self._spec_round(finished)
-            return finished
-        with tr.span("decode", tid="engine", chunk=self.decode_chunk), \
-                self._ann("serve.decode"):
-            toks, lps, masks, self.pages = self._decode(
-                self.params, jnp.asarray(self._last_tok), self.pages,
-                self._dev("tables", self._tables), jnp.asarray(self._pos),
-                self._dev("active", self._active),
-                self._dev("remaining", remaining),
-                self._dev("slot_shard", self._slot_shard),
-                self._next_key())
-            toks_np = np.asarray(toks)       # [chunk, B]
-            lps_np = np.asarray(lps)
-            masks_np = np.asarray(masks)
+        return remaining
+
+    def _record_decode(self, toks_np: np.ndarray, lps_np: np.ndarray,
+                       masks_np: np.ndarray,
+                       finished: List[ServedTrajectory]) -> None:
+        """Book a decode chunk's ``[chunk, B]`` results, each slot's
+        tokens in order up to its first masked step."""
         self.stats.occupancy_sum += float(masks_np.sum())
         self.stats.decode_steps += self.decode_chunk
         for req in list(self.scheduler.running):
@@ -1406,7 +1474,6 @@ class ServeEngine:
                     break
                 self._record(req, int(toks_np[t, slot]),
                              float(lps_np[t, slot]), finished)
-        return finished
 
     def _assert_write_pages_private(self) -> None:
         """Invariant guard: the page a slot's next decode write lands in
@@ -1501,43 +1568,59 @@ class ServeEngine:
         for req in self.scheduler.running:
             cap[req.slot] = len(req.blocks) * self.block_size
         if isinstance(self.draft, ModelDraft):
-            with tr.span("draft", tid="engine", k=k), \
-                    self._ann("serve.draft"):
-                draft_toks, draft_logits, self.draft.pages = \
-                    self._draft_fn(k)(
-                        self.draft.params, jnp.asarray(self._last_tok),
-                        self.draft.pages,
-                        self._dev("tables", self._tables),
-                        jnp.asarray(self._pos),
-                        self._dev("active", self._active),
-                        self._dev("cap", cap),
-                        self._dev("slot_shard", self._slot_shard),
-                        self._next_key())
+            with tr.span("draft", tid="engine", k=k):
+                with tr.span("upload", tid="engine"):
+                    token = jnp.asarray(self._last_tok)
+                    args = (self._dev("tables", self._tables),
+                            jnp.asarray(self._pos),
+                            self._dev("active", self._active),
+                            self._dev("cap", cap),
+                            self._dev("slot_shard", self._slot_shard),
+                            self._next_key())
+                with tr.span("dispatch", tid="engine"):
+                    draft_toks, draft_logits, self.draft.pages = \
+                        self._draft_fn(k)(self.draft.params, token,
+                                          self.draft.pages, *args)
         else:
             prop_np = np.zeros((self.max_batch, k), np.int32)
             for req in self.scheduler.running:
                 prop = np.asarray(
                     self.draft.fn(req, k), np.int32).reshape(-1)[:k]
                 prop_np[req.slot, :prop.shape[0]] = prop
-            draft_toks = jnp.asarray(prop_np)
             # One-hot proposal logits, built host-side (one transfer
             # instead of per-round device compare/where dispatches).
             vocab = self.bundle.cfg.vocab_size
             oh = np.full((self.max_batch, k, vocab), -1e9, np.float32)
             np.put_along_axis(oh, prop_np[..., None], 0.0, axis=-1)
-            draft_logits = jnp.asarray(oh)
-        with tr.span("verify", tid="engine", k=k), \
-                self._ann("serve.verify"):
-            toks, lps, n_acc, n_emit, self.pages = self._verify_fn(k)(
-                self.params, jnp.asarray(self._last_tok), draft_toks,
-                draft_logits, self.pages,
-                self._dev("tables", self._tables),
-                jnp.asarray(self._pos), self._dev("active", self._active),
-                self._dev("cap", cap),
-                self._dev("slot_shard", self._slot_shard),
-                self._next_key())
-            toks_np, lps_np, n_acc_np, n_emit_np = jax.device_get(
-                (toks, lps, n_acc, n_emit))
+            with tr.span("upload", tid="engine"):
+                draft_toks = jnp.asarray(prop_np)
+                draft_logits = jnp.asarray(oh)
+        with tr.span("verify", tid="engine", k=k):
+            with tr.span("upload", tid="engine"):
+                token = jnp.asarray(self._last_tok)
+                args = (self._dev("tables", self._tables),
+                        jnp.asarray(self._pos),
+                        self._dev("active", self._active),
+                        self._dev("cap", cap),
+                        self._dev("slot_shard", self._slot_shard),
+                        self._next_key())
+            with tr.span("dispatch", tid="engine"):
+                toks, lps, n_acc, n_emit, self.pages = self._verify_fn(k)(
+                    self.params, token, draft_toks, draft_logits,
+                    self.pages, *args)
+            with tr.span("result_wait", tid="engine"):
+                toks_np, lps_np, n_acc_np, n_emit_np = jax.device_get(
+                    (toks, lps, n_acc, n_emit))
+            with tr.span("record", tid="engine"):
+                self._record_spec(k, toks_np, lps_np, n_acc_np, n_emit_np,
+                                  finished)
+
+    def _record_spec(self, k: int, toks_np: np.ndarray, lps_np: np.ndarray,
+                     n_acc_np: np.ndarray, n_emit_np: np.ndarray,
+                     finished: List[ServedTrajectory]) -> None:
+        """Book a verify round: acceptance statistics, then each slot's
+        accepted prefix and correction token."""
+        tr = self.tracer
         n_active = int(self._active.sum())
         self.stats.decode_steps += 1
         self.stats.occupancy_sum += float(n_active)

@@ -20,8 +20,9 @@ from repro.obs import (
     export_perfetto,
     export_trace_jsonl,
     load_trace_events,
+    ProfilerTracer,
     make_tracer,
-    trace_annotation,
+    mirrored,
 )
 
 
@@ -227,9 +228,67 @@ def test_export_roundtrip_both_formats(tmp_path):
         assert a["ts"] == pytest.approx(b["ts"], abs=1e-6)
 
 
-def test_trace_annotation_is_usable_context():
-    with trace_annotation("serve.decode"):
-        pass                          # jax present or not: must not raise
+def _events(tr):
+    return [(e.ph, e.name, e.pid, e.tid, e.args, e.id) for e in tr.events()]
+
+
+def _spans(tr):
+    with tr.span("step", tid="engine", n=3):
+        with tr.span("decode", tid="engine", chunk=4):
+            tr.instant("swap", tid="engine", old=0, new=1)
+        tr.counter("pool_free", free=12.0)
+    with tr.span("produce", pid="runtime", tid="producer"):
+        pass
+
+
+def _profiled(log_dir, fn):
+    """Host annotation names and their stats from a ``jax.profiler``
+    capture of ``fn()``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(pathlib.Path(log_dir).glob("**/*.xplane.pb"))[-1]
+    data = ProfileData.from_file(str(path))
+    return [(ev.name, dict(ev.stats)) for plane in data.planes
+            if plane.name.startswith("/host")
+            for line in plane.lines for ev in line.events]
+
+
+def test_null_tracer_mirrors_nothing(tmp_path):
+    names = [n for n, _ in _profiled(tmp_path, lambda: _spans(NULL_TRACER))]
+    assert not [n for n in names if n.startswith(("serve.", "runtime."))]
+    assert not NULL_TRACER.mirror and len(NULL_TRACER) == 0
+    prof = mirrored(NULL_TRACER)      # NULL_TRACER itself stays a no-op
+    assert isinstance(prof, ProfilerTracer) and prof is not NULL_TRACER
+    assert not NULL_TRACER.mirror
+
+
+def test_profiler_tracer_keeps_no_ring(tmp_path):
+    prof = mirrored(None)
+    assert isinstance(prof, ProfilerTracer)
+    assert prof.mirror and not prof.enabled and mirrored(prof) is prof
+    got = _profiled(tmp_path, lambda: _spans(prof))
+    mine = [(n, st) for n, st in got if n.startswith(("serve.", "runtime."))]
+    # sync spans only, named pid.name, with none of the span's args
+    assert [n for n, _ in mine] == ["serve.step", "serve.decode",
+                                    "runtime.produce"]
+    assert all("n" not in st and "chunk" not in st for _, st in mine)
+    assert len(prof) == 0 and prof.events() == []
+
+
+def test_mirrored_ring_keeps_its_events(tmp_path):
+    plain, ring = Tracer(detail="spans"), Tracer(detail="spans")
+    assert mirrored(ring) is ring and ring.mirror and not plain.mirror
+    _spans(plain)
+    got = _profiled(tmp_path, lambda: _spans(ring))
+    assert _events(ring) == _events(plain)
+    assert [n for n, _ in got if n.startswith(("serve.", "runtime."))] \
+        == ["serve.step", "serve.decode", "runtime.produce"]
 
 
 # --- trace_report validation ------------------------------------------------

@@ -254,16 +254,26 @@ def test_engine_windowed_speculative_token_exact():
 # --- in-flight weight swap (acceptance: per-token version provenance) -------
 
 
+# The swap tests follow one sampled request across a publish: an EOS
+# sampled before it would end the request early, whatever the sample
+# stream.  Their readout gives EOS a logit no sample reaches (the bias
+# form of the zero stop-id readout row the chip benchmark's weights
+# use), so every request runs to its budget.
+SWAP_PARAMS = {**PARAMS, "lm_head": {
+    **PARAMS["lm_head"],
+    "b": jnp.zeros((CFG.vocab_size,), jnp.float32).at[EOS].set(-1e9)}}
+
+
 def _swap_trajectory(seed=3, swap_after=5, total=12):
     """Fixed-seed run with one learner publish mid-generation."""
-    store = PolicyStore(PARAMS, capacity=4)
+    store = PolicyStore(SWAP_PARAMS, capacity=4)
     eng = ServeEngine(BUNDLE, store=store, num_blocks=32, block_size=4,
                       max_batch=2, max_seq_len=64, temperature=1.0,
                       seed=seed)
     eng.submit(PROMPTS[0], total)
     for _ in range(swap_after):
         assert not eng.step()
-    p2 = jax.tree.map(lambda x: x + 0.01, PARAMS)
+    p2 = jax.tree.map(lambda x: x + 0.01, SWAP_PARAMS)
     store.publish(p2)
     trajs = eng.run(max_steps=200)
     return trajs[0], p2, eng
@@ -442,14 +452,14 @@ def test_tracing_swap_provenance_matches_versions():
     stream agree with the trajectory's recorded provenance, and the
     swap-to-first-stale-token histogram fires exactly once."""
     tr = Tracer(detail="full")
-    store = PolicyStore(PARAMS, capacity=4)
+    store = PolicyStore(SWAP_PARAMS, capacity=4)
     eng = ServeEngine(BUNDLE, store=store, num_blocks=32, block_size=4,
                       max_batch=2, max_seq_len=64, temperature=1.0,
                       seed=3, tracer=tr)
     eng.submit(PROMPTS[0], 12)
     for _ in range(5):
         assert not eng.step()
-    store.publish(jax.tree.map(lambda x: x + 0.01, PARAMS))
+    store.publish(jax.tree.map(lambda x: x + 0.01, SWAP_PARAMS))
     traj = eng.run(max_steps=200)[0]
     _assert_balanced(tr.events())
 
@@ -512,3 +522,55 @@ def test_tracing_off_emits_nothing_and_matches_traced_run():
     traced = _run(tr)
     for a, b in zip(plain, traced):
         np.testing.assert_array_equal(a, b)
+
+
+ROUND_PHASES = ["serve.schedule", "serve.upload", "serve.dispatch",
+                "serve.result_wait", "serve.record"]
+
+
+def _host_spans(log_dir):
+    """``serve.*`` annotations of a ``jax.profiler`` capture, as
+    ``(start_ns, end_ns, name)`` in start order."""
+    from jax.profiler import ProfileData
+
+    path = sorted(log_dir.glob("**/*.xplane.pb"))[-1]
+    data = ProfileData.from_file(str(path))
+    return sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                  for plane in data.planes if plane.name.startswith("/host")
+                  for line in plane.lines for ev in line.events
+                  if ev.name.startswith("serve."))
+
+
+def test_annotated_rounds_nest_their_phases_in_the_profile(tmp_path):
+    """``annotate=True`` with no tracer: a profiler capture of a varlen
+    round and a decode round holds one ``serve.step`` per round, with
+    the round's phases nested in it in order, and the varlen round's
+    result wait inside ``serve.chunked_round``."""
+    eng = ServeEngine(BUNDLE, PARAMS, num_blocks=32, block_size=4,
+                      max_batch=2, max_seq_len=64, temperature=1e-4,
+                      seed=0, annotate=True)
+    eng.submit(PROMPTS[0], 4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.step()                      # the prompt's tile: varlen
+        eng.step()                      # one decode chunk
+    finally:
+        jax.profiler.stop_trace()
+    assert eng.stats.tokens_out == 2
+    spans = _host_spans(tmp_path)
+    steps = [s for s in spans if s[2] == "serve.step"]
+    assert len(steps) == 2
+    for (a, b, _), outer in zip(steps, ("serve.chunked_round",
+                                        "serve.decode")):
+        inner = [s for s in spans if a <= s[0] and s[1] <= b
+                 and s[2] != "serve.step"]
+        assert [s[2] for s in inner] == \
+            [ROUND_PHASES[0], outer] + ROUND_PHASES[1:]
+        phases = [s for s in inner if s[2] in ROUND_PHASES]
+        for prev, nxt in zip(phases, phases[1:]):
+            assert prev[1] <= nxt[0]
+        # the round's span encloses its upload, dispatch, result wait
+        # and bookkeeping
+        o0, o1, _ = inner[1]
+        assert all(o0 <= s[0] and s[1] <= o1 for s in phases[1:])
+    assert len(spans) == 2 * (len(ROUND_PHASES) + 2)
