@@ -112,23 +112,25 @@ def attention(
 
 
 def _paged_attention_local(
-    q, k_pages, v_pages, block_tables, context_lens, *, window, mode,
+    q, k_pages, v_pages, block_tables, context_lens, *, layer, window, mode,
 ):
     kw = _pallas_kwargs(mode)
     if kw is None:
         return ref_mod.ref_paged_attention(
-            q, k_pages, v_pages, block_tables, context_lens, window=window)
+            q, k_pages[layer], v_pages[layer], block_tables, context_lens,
+            window=window)
     return paged_attention_pallas(
         q, k_pages, v_pages, block_tables, context_lens,
-        window=window, **kw)
+        layer=layer, window=window, **kw)
 
 
 def paged_attention(
     q, k_pages, v_pages, block_tables, context_lens,
-    *, window: Optional[int] = None, mode: Optional[str] = None,
+    *, layer, window: Optional[int] = None, mode: Optional[str] = None,
     mesh=None, slot_shard=None, axis_name: str = "data",
 ):
-    """Decode attention over a block-table paged KV pool ([B, H, D]).
+    """Decode attention over layer ``layer`` of a block-table paged KV
+    pool (``[L, KV, NB, BS, lanes]``, read in place; q ``[B, H, D]``).
 
     With a ``mesh``, ``k_pages``/``v_pages`` are NB-sharded over
     ``axis_name``, ``block_tables`` hold shard-local page ids, and
@@ -136,112 +138,120 @@ def paged_attention(
     device attends over its local pool with foreign slots masked to
     context 0 (exact zero output) and a ``psum`` recombines the batch.
     """
+    layer = jnp.asarray(layer, jnp.int32)
     if not _sharded(mesh, axis_name):
         return _paged_attention_local(
             q, k_pages, v_pages, block_tables, context_lens,
-            window=window, mode=mode)
+            layer=layer, window=window, mode=mode)
 
-    def body(q, kp, vp, tbl, lens, ss):
+    def body(q, kp, vp, tbl, lens, ly, ss):
         idx = jax.lax.axis_index(axis_name)
         local_lens = jnp.where(ss == idx, lens, 0).astype(jnp.int32)
         out = _paged_attention_local(
-            q, kp, vp, tbl, local_lens, window=window, mode=mode)
+            q, kp, vp, tbl, local_lens, layer=ly, window=window, mode=mode)
         return jax.lax.psum(out, axis_name)
 
-    pool = P(None, axis_name, None, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), pool, pool, P(), P(), P()),
-        out_specs=P(), check_vma=False,
-    )(q, k_pages, v_pages, block_tables, context_lens,
-      slot_shard.astype(jnp.int32))
-
-
-def _paged_attention_multi_local(
-    q, k_pages, v_pages, block_tables, context_lens, *, window, mode,
-):
-    kw = _pallas_kwargs(mode)
-    if kw is None:
-        return ref_mod.ref_paged_attention_multi(
-            q, k_pages, v_pages, block_tables, context_lens, window=window)
-    return paged_attention_multi_pallas(
-        q, k_pages, v_pages, block_tables, context_lens,
-        window=window, **kw)
-
-
-def paged_attention_multi(
-    q, k_pages, v_pages, block_tables, context_lens,
-    *, window: Optional[int] = None, mode: Optional[str] = None,
-    mesh=None, slot_shard=None, axis_name: str = "data",
-):
-    """Multi-token verify attention over the paged pool ([B, T, H, D]):
-    query ``t`` sits at absolute position ``context_lens - T + t`` and
-    attends causally — T drafted tokens scored in one dispatch.  Mesh
-    semantics match :func:`paged_attention` (local tables + psum)."""
-    if not _sharded(mesh, axis_name):
-        return _paged_attention_multi_local(
-            q, k_pages, v_pages, block_tables, context_lens,
-            window=window, mode=mode)
-
-    def body(q, kp, vp, tbl, lens, ss):
-        idx = jax.lax.axis_index(axis_name)
-        local_lens = jnp.where(ss == idx, lens, 0).astype(jnp.int32)
-        out = _paged_attention_multi_local(
-            q, kp, vp, tbl, local_lens, window=window, mode=mode)
-        return jax.lax.psum(out, axis_name)
-
-    pool = P(None, axis_name, None, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), pool, pool, P(), P(), P()),
-        out_specs=P(), check_vma=False,
-    )(q, k_pages, v_pages, block_tables, context_lens,
-      slot_shard.astype(jnp.int32))
-
-
-def _paged_attention_varlen_local(
-    q, k_pages, v_pages, block_tables, row_start, row_len, *, window, mode,
-):
-    kw = _pallas_kwargs(mode)
-    if kw is None:
-        return ref_mod.ref_paged_attention_varlen(
-            q, k_pages, v_pages, block_tables, row_start, row_len,
-            window=window)
-    return paged_attention_varlen_pallas(
-        q, k_pages, v_pages, block_tables, row_start, row_len,
-        window=window, **kw)
-
-
-def paged_attention_varlen(
-    q, k_pages, v_pages, block_tables, row_start, row_len,
-    *, window: Optional[int] = None, mode: Optional[str] = None,
-    mesh=None, slot_shard=None, axis_name: str = "data",
-):
-    """Ragged multi-token attention over the paged pool ([B, T, H, D]):
-    query ``t < row_len[b]`` sits at absolute position ``row_start[b] +
-    t`` and attends causally; padding rows and ``row_len == 0`` slots
-    come back exactly zero.  Decode, speculative verify and chunked
-    prefill tiles are call shapes of this one kernel.  Mesh semantics
-    match :func:`paged_attention` — foreign slots are masked to
-    ``row_len 0`` (exact zero) and a ``psum`` recombines the batch."""
-    if not _sharded(mesh, axis_name):
-        return _paged_attention_varlen_local(
-            q, k_pages, v_pages, block_tables, row_start, row_len,
-            window=window, mode=mode)
-
-    def body(q, kp, vp, tbl, rs, rl, ss):
-        idx = jax.lax.axis_index(axis_name)
-        local_len = jnp.where(ss == idx, rl, 0).astype(jnp.int32)
-        out = _paged_attention_varlen_local(
-            q, kp, vp, tbl, rs, local_len, window=window, mode=mode)
-        return jax.lax.psum(out, axis_name)
-
-    pool = P(None, axis_name, None, None)
+    pool = P(None, None, axis_name, None, None)
     return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), pool, pool, P(), P(), P(), P()),
         out_specs=P(), check_vma=False,
-    )(q, k_pages, v_pages, block_tables, row_start, row_len,
+    )(q, k_pages, v_pages, block_tables, context_lens, layer,
+      slot_shard.astype(jnp.int32))
+
+
+def _paged_attention_multi_local(
+    q, k_pages, v_pages, block_tables, context_lens, *, layer, window, mode,
+):
+    kw = _pallas_kwargs(mode)
+    if kw is None:
+        return ref_mod.ref_paged_attention_multi(
+            q, k_pages[layer], v_pages[layer], block_tables, context_lens,
+            window=window)
+    return paged_attention_multi_pallas(
+        q, k_pages, v_pages, block_tables, context_lens,
+        layer=layer, window=window, **kw)
+
+
+def paged_attention_multi(
+    q, k_pages, v_pages, block_tables, context_lens,
+    *, layer, window: Optional[int] = None, mode: Optional[str] = None,
+    mesh=None, slot_shard=None, axis_name: str = "data",
+):
+    """Multi-token verify attention over layer ``layer`` of the paged
+    pool ([B, T, H, D]): query ``t`` sits at absolute position
+    ``context_lens - T + t`` and attends causally — T drafted tokens
+    scored in one dispatch.  Pool layout and mesh semantics match
+    :func:`paged_attention` (local tables + psum)."""
+    layer = jnp.asarray(layer, jnp.int32)
+    if not _sharded(mesh, axis_name):
+        return _paged_attention_multi_local(
+            q, k_pages, v_pages, block_tables, context_lens,
+            layer=layer, window=window, mode=mode)
+
+    def body(q, kp, vp, tbl, lens, ly, ss):
+        idx = jax.lax.axis_index(axis_name)
+        local_lens = jnp.where(ss == idx, lens, 0).astype(jnp.int32)
+        out = _paged_attention_multi_local(
+            q, kp, vp, tbl, local_lens, layer=ly, window=window, mode=mode)
+        return jax.lax.psum(out, axis_name)
+
+    pool = P(None, None, axis_name, None, None)
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(), pool, pool, P(), P(), P(), P()),
+        out_specs=P(), check_vma=False,
+    )(q, k_pages, v_pages, block_tables, context_lens, layer,
+      slot_shard.astype(jnp.int32))
+
+
+def _paged_attention_varlen_local(
+    q, k_pages, v_pages, block_tables, row_start, row_len,
+    *, layer, window, mode,
+):
+    kw = _pallas_kwargs(mode)
+    if kw is None:
+        return ref_mod.ref_paged_attention_varlen(
+            q, k_pages[layer], v_pages[layer], block_tables, row_start,
+            row_len, window=window)
+    return paged_attention_varlen_pallas(
+        q, k_pages, v_pages, block_tables, row_start, row_len,
+        layer=layer, window=window, **kw)
+
+
+def paged_attention_varlen(
+    q, k_pages, v_pages, block_tables, row_start, row_len,
+    *, layer, window: Optional[int] = None, mode: Optional[str] = None,
+    mesh=None, slot_shard=None, axis_name: str = "data",
+):
+    """Ragged multi-token attention over layer ``layer`` of the paged
+    pool ([B, T, H, D]): query ``t < row_len[b]`` sits at absolute
+    position ``row_start[b] + t`` and attends causally; padding rows and
+    ``row_len == 0`` slots come back exactly zero.  Decode, speculative
+    verify and chunked prefill tiles are call shapes of this one kernel.
+    Pool layout and mesh semantics match :func:`paged_attention` —
+    foreign slots are masked to ``row_len 0`` (exact zero) and a
+    ``psum`` recombines the batch."""
+    layer = jnp.asarray(layer, jnp.int32)
+    if not _sharded(mesh, axis_name):
+        return _paged_attention_varlen_local(
+            q, k_pages, v_pages, block_tables, row_start, row_len,
+            layer=layer, window=window, mode=mode)
+
+    def body(q, kp, vp, tbl, rs, rl, ly, ss):
+        idx = jax.lax.axis_index(axis_name)
+        local_len = jnp.where(ss == idx, rl, 0).astype(jnp.int32)
+        out = _paged_attention_varlen_local(
+            q, kp, vp, tbl, rs, local_len, layer=ly, window=window,
+            mode=mode)
+        return jax.lax.psum(out, axis_name)
+
+    pool = P(None, None, axis_name, None, None)
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(), pool, pool, P(), P(), P(), P(), P()),
+        out_specs=P(), check_vma=False,
+    )(q, k_pages, v_pages, block_tables, row_start, row_len, layer,
       slot_shard.astype(jnp.int32))
 
 

@@ -14,12 +14,19 @@ exactly the pages its requests own:
   BlockSpec index maps read ``tables[b, j]`` to pick the HBM page to
   stream, which is the whole trick — the gather happens in the DMA
   engine, not in compute.
-* pages are laid out ``[KV, NB, BS, lanes]`` (kv-head major, rows
-  lane-padded, see ``models.transformer.init_paged_cache``) so one grid
-  step streams a single ``[BS, lanes]`` tile; the query block is one kv
-  head's whole group, ``[T * G, lanes]`` (row ``r`` is query ``r // G``),
-  so every block's trailing dims are its array's own, as the TPU
-  compiler requires of blocks below its (8, 128) tile.
+* the kernel reads the engine's whole pool, ``[L, KV, NB, BS, lanes]``
+  (kv-head major, rows lane-padded, see
+  ``models.transformer.init_paged_cache``), in place: the layer rides
+  in as a fourth scalar-prefetch operand and the k/v index maps pick
+  ``(layer, g, tables[b, j])``, so no per-layer slice of the pool is
+  ever materialised (a ``pallas_call`` operand is a buffer of its own,
+  so ``pool[layer]`` would copy the layer's every page each call).  The
+  layer is traced, not static: all layers share one trace and one
+  lowering of the kernel at each call shape.
+* one grid step streams a single ``[BS, lanes]`` tile; the query block
+  is one kv head's whole group, ``[T * G, lanes]`` (row ``r`` is query
+  ``r // G``), so every block's trailing dims are its array's own, as
+  the TPU compiler requires of blocks below its (8, 128) tile.
 * ragged sequences: query ``t < row_len[b]`` of request ``b`` sits at
   absolute position ``row_start[b] + t`` and attends causally over its
   own prefix; rows ``t >= row_len[b]`` are padding and come back exactly
@@ -63,9 +70,10 @@ def _paged_varlen_kernel(
     tables_ref,   # scalar prefetch [B, M] int32
     start_ref,    # scalar prefetch [B] int32 (abs position of query row 0)
     len_ref,      # scalar prefetch [B] int32 (live query rows, 0 = inactive)
+    layer_ref,    # scalar prefetch [1] int32 (read by the k/v index maps)
     q_ref,        # [1, 1, T*G, D] one kv group's query heads, t-major
-    k_ref,        # [1, 1, BS, D]
-    v_ref,        # [1, 1, BS, D]
+    k_ref,        # [BS, D] one page of one layer's kv head
+    v_ref,        # [BS, D]
     o_ref,        # [1, 1, T*G, D]
     m_ref,        # scratch [T*G, 1]
     l_ref,        # scratch [T*G, 1]
@@ -77,6 +85,7 @@ def _paged_varlen_kernel(
     window: Optional[int],
     scale: float,
 ):
+    del layer_ref
     b = pl.program_id(0)
     j = pl.program_id(2)
     base = start_ref[b]           # absolute position of query 0
@@ -102,8 +111,8 @@ def _paged_varlen_kernel(
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # [R, D]
-        k = k_ref[0, 0].astype(jnp.float32)                  # [BS, D]
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)                   # [BS, D]
+        v = v_ref[...].astype(jnp.float32)
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)              # [R, BS]
@@ -145,12 +154,13 @@ def _paged_varlen_kernel(
 )
 def paged_attention_varlen(
     q: jax.Array,             # [B, T, H, D] ragged query chunks, right-padded
-    k_pages: jax.Array,       # [KV, NB, BS, lanes >= D]
-    v_pages: jax.Array,       # [KV, NB, BS, lanes >= D]
+    k_pages: jax.Array,       # [L, KV, NB, BS, lanes >= D] the whole pool
+    v_pages: jax.Array,       # [L, KV, NB, BS, lanes >= D]
     block_tables: jax.Array,  # [B, M] int32 page ids (pads must be in-range)
     row_start: jax.Array,     # [B] int32 abs position of query row 0
     row_len: jax.Array,       # [B] int32 live rows per slot (0 = inactive)
     *,
+    layer: jax.Array,         # int32 scalar: the pool layer to read
     window: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -162,9 +172,10 @@ def paged_attention_varlen(
     does a slot with ``row_len[b] == 0``.  Decode (``row_len == 1``),
     speculative verify (``row_len == k``) and chunked prefill tiles are
     all this one kernel called with different ``(row_start, row_len)``
-    tables."""
+    tables.  Keys and values are read from layer ``layer`` of the pool,
+    in place."""
     b, t, h, d = q.shape
-    kv, _, block_size, lanes = k_pages.shape
+    _, kv, _, block_size, lanes = k_pages.shape
     m = block_tables.shape[1]
     assert h % kv == 0, (h, kv)
     group = h // kv
@@ -177,25 +188,30 @@ def paged_attention_varlen(
     qg = q.reshape(b, t, kv, group, d).transpose(0, 2, 1, 3, 4)
     qg = pad_lanes(qg.reshape(b, kv, rows, d), lanes)
 
+    # One page of one layer's kv head: the leading three dims are
+    # squeezed, the trailing two are the pool's own.
+    page = (pl.Squeezed(), pl.Squeezed(), pl.Squeezed(), block_size, lanes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, kv, m),
         in_specs=[
             pl.BlockSpec(
                 (1, 1, rows, lanes),
-                lambda b_, g_, j, tbl, rs, rl: (b_, g_, 0, 0)),
+                lambda b_, g_, j, tbl, rs, rl, ly: (b_, g_, 0, 0)),
             pl.BlockSpec(
-                (1, 1, block_size, lanes),
-                lambda b_, g_, j, tbl, rs, rl: (g_, tbl[b_, j], 0, 0),
+                page,
+                lambda b_, g_, j, tbl, rs, rl, ly: (
+                    ly[0], g_, tbl[b_, j], 0, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_size, lanes),
-                lambda b_, g_, j, tbl, rs, rl: (g_, tbl[b_, j], 0, 0),
+                page,
+                lambda b_, g_, j, tbl, rs, rl, ly: (
+                    ly[0], g_, tbl[b_, j], 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, rows, lanes),
-            lambda b_, g_, j, tbl, rs, rl: (b_, g_, 0, 0)),
+            lambda b_, g_, j, tbl, rs, rl, ly: (b_, g_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
@@ -211,7 +227,8 @@ def paged_attention_varlen(
         out_shape=jax.ShapeDtypeStruct((b, kv, rows, lanes), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), row_start.astype(jnp.int32),
-      row_len.astype(jnp.int32), qg, k_pages, v_pages)
+      row_len.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      qg, k_pages, v_pages)
     out = out[..., :d].reshape(b, kv, t, group, d).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, t, h, d)
 
@@ -221,11 +238,12 @@ def paged_attention_varlen(
 )
 def paged_attention(
     q: jax.Array,             # [B, H, D]
-    k_pages: jax.Array,       # [KV, NB, BS, lanes >= D]
-    v_pages: jax.Array,       # [KV, NB, BS, lanes >= D]
+    k_pages: jax.Array,       # [L, KV, NB, BS, lanes >= D] the whole pool
+    v_pages: jax.Array,       # [L, KV, NB, BS, lanes >= D]
     block_tables: jax.Array,  # [B, M] int32 page ids (pads must be in-range)
     context_lens: jax.Array,  # [B] int32
     *,
+    layer: jax.Array,         # int32 scalar: the pool layer to read
     window: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -238,7 +256,7 @@ def paged_attention(
     out = paged_attention_varlen(
         q[:, None], k_pages, v_pages, block_tables,
         jnp.where(active, context_lens - 1, 0), active.astype(jnp.int32),
-        window=window, interpret=interpret)
+        layer=layer, window=window, interpret=interpret)
     return out[:, 0]
 
 
@@ -247,11 +265,12 @@ def paged_attention(
 )
 def paged_attention_multi(
     q: jax.Array,             # [B, T, H, D] consecutive query tokens
-    k_pages: jax.Array,       # [KV, NB, BS, lanes >= D]
-    v_pages: jax.Array,       # [KV, NB, BS, lanes >= D]
+    k_pages: jax.Array,       # [L, KV, NB, BS, lanes >= D] the whole pool
+    v_pages: jax.Array,       # [L, KV, NB, BS, lanes >= D]
     block_tables: jax.Array,  # [B, M] int32 page ids (pads must be in-range)
     context_lens: jax.Array,  # [B] int32 rows live *including* the T chunk
     *,
+    layer: jax.Array,         # int32 scalar: the pool layer to read
     window: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -266,4 +285,4 @@ def paged_attention_multi(
     row_len = jnp.where(active, t, 0)
     return paged_attention_varlen(
         q, k_pages, v_pages, block_tables, row_start, row_len,
-        window=window, interpret=interpret)
+        layer=layer, window=window, interpret=interpret)

@@ -327,8 +327,13 @@ def decode_step_paged(
     a ``lax.scan`` so the pool never rides a scan as a carried value:
     each layer's row append is an in-place-able op
     (``kernels.ops.paged_kv_write`` — aliased Pallas DMA scatter, or its
-    dynamic-update-slice oracle), which keeps per-step cost O(rows
-    written), independent of ``num_blocks``.  The scan-carried
+    dynamic-update-slice oracle), and each layer's attention reads the
+    whole pool in place at a traced layer index
+    (``kernels.ops.paged_attention``), so no layer's pages are ever
+    sliced out of the pool.  That keeps per-step cost O(rows written
+    and pages read), independent of ``num_blocks``.  The data
+    dependency through each layer's tail orders layer ``l``'s read
+    before layer ``l + 1``'s in-place write.  The scan-carried
     formulation made XLA rewrite the whole ``[L, KV, NB, BS, Dh]`` pool
     every step (~2.7x slower at 128 vs 16 blocks at equal work); it is
     kept as :func:`decode_step_paged_carried` as the equivalence oracle
@@ -368,8 +373,8 @@ def decode_step_paged(
             mesh=mesh, slot_shard=slot_shard,
         )
         attn_out = kops.paged_attention(
-            q[:, 0], k_pages[layer], v_pages[layer], block_tables,
-            context_lens, window=cfg.window_for_layer(layer),
+            q[:, 0], k_pages, v_pages, block_tables, context_lens,
+            layer=layer, window=cfg.window_for_layer(layer),
             mode=kernel_mode, mesh=mesh, slot_shard=slot_shard,
         )
         x = _paged_layer_tail(cfg, lp, x, attn_out)
@@ -441,8 +446,8 @@ def decode_step_paged_multi(
             layer=layer, kernel_mode=kernel_mode, mesh=mesh,
             slot_shard=slot_shard)
         attn_out = kops.paged_attention_multi(
-            q, k_pages[layer], v_pages[layer], block_tables,
-            context_lens, window=cfg.window_for_layer(layer),
+            q, k_pages, v_pages, block_tables, context_lens,
+            layer=layer, window=cfg.window_for_layer(layer),
             mode=kernel_mode, mesh=mesh, slot_shard=slot_shard,
         )
         x = _paged_layer_tail(cfg, lp, x, attn_out)
@@ -505,8 +510,8 @@ def decode_step_paged_varlen(
             layer=layer, kernel_mode=kernel_mode, mesh=mesh,
             slot_shard=slot_shard)
         attn_out = kops.paged_attention_varlen(
-            q, k_pages[layer], v_pages[layer], block_tables,
-            safe_start, row_len, window=cfg.window_for_layer(layer),
+            q, k_pages, v_pages, block_tables, safe_start, row_len,
+            layer=layer, window=cfg.window_for_layer(layer),
             mode=kernel_mode, mesh=mesh, slot_shard=slot_shard,
         )
         x = _paged_layer_tail(cfg, lp, x, attn_out)
@@ -576,9 +581,11 @@ def decode_step_paged_carried(
             k_rows.astype(k_pages.dtype), mode="drop")
         v_pages = v_pages.at[:, page_idx, offset, :].set(
             v_rows.astype(v_pages.dtype), mode="drop")
+        # The kernel reads a whole pool by layer: this scan step's
+        # per-layer pool is a pool of one layer.
         attn_out = kops.paged_attention(
-            q[:, 0], k_pages, v_pages, block_tables, context_lens,
-            mode=kernel_mode,
+            q[:, 0], k_pages[None], v_pages[None], block_tables,
+            context_lens, layer=0, mode=kernel_mode,
         )
         x = _paged_layer_tail(cfg, lp, x, attn_out)
         return x, {"k_pages": k_pages, "v_pages": v_pages}

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _layered_pool import layer_cases, layered
 from repro.kernels import ref
 from repro.kernels.flash_attention_pallas import flash_attention
 from repro.kernels.fused_logprob_pallas import logprobs_pallas
@@ -103,12 +104,18 @@ def _ragged_tables(rng, b, num_blocks, max_blocks, block_size,
 
 
 @pytest.mark.parametrize(
-    "b,h,kv,d,bs,window",
-    [(4, 4, 2, 16, 8, None), (3, 4, 4, 32, 4, None), (2, 8, 2, 16, 8, 5),
-     (5, 2, 1, 8, 16, None), (4, 4, 2, 16, 8, 12)],
+    "b,h,kv,d,bs,window,layers,layer",
+    layer_cases(
+        [(4, 4, 2, 16, 8, None), (3, 4, 4, 32, 4, None),
+         (2, 8, 2, 16, 8, 5), (5, 2, 1, 8, 16, None),
+         (4, 4, 2, 16, 8, 12)],
+        (4, 4, 2, 16, 8, None)),
 )
-def test_paged_attention_ragged_sweep(b, h, kv, d, bs, window):
-    """Pallas kernel vs jnp oracle on shuffled, ragged block tables."""
+def test_paged_attention_ragged_sweep(b, h, kv, d, bs, window, layers,
+                                      layer):
+    """Pallas kernel vs jnp oracle on shuffled, ragged block tables;
+    the kernel reads layer ``layer`` of the whole pool in place, the
+    oracle that layer alone."""
     rng = np.random.default_rng(b * 31 + h)
     num_blocks, max_blocks = 24, 4
     ks = jax.random.split(jax.random.fold_in(KEY, b * h * d), 3)
@@ -116,8 +123,10 @@ def test_paged_attention_ragged_sweep(b, h, kv, d, bs, window):
     kp = jax.random.normal(ks[1], (kv, num_blocks, bs, d))
     vp = jax.random.normal(ks[2], (kv, num_blocks, bs, d))
     tables, lens = _ragged_tables(rng, b, num_blocks, max_blocks, bs)
-    out = paged_attention(q, kp, vp, jnp.asarray(tables),
-                          jnp.asarray(lens), window=window, interpret=True)
+    out = paged_attention(q, layered(kp, layers, layer),
+                          layered(vp, layers, layer), jnp.asarray(tables),
+                          jnp.asarray(lens), layer=layer, window=window,
+                          interpret=True)
     want = ref.ref_paged_attention(q, kp, vp, jnp.asarray(tables),
                                    jnp.asarray(lens), window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -133,7 +142,8 @@ def test_paged_attention_inactive_slot_zero_output():
     tables = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
     lens = jnp.asarray([0, 6], jnp.int32)
     for fn in (
-        lambda: paged_attention(q, kp, vp, tables, lens, interpret=True),
+        lambda: paged_attention(q, kp[None], vp[None], tables, lens,
+                                layer=0, interpret=True),
         lambda: ref.ref_paged_attention(q, kp, vp, tables, lens),
     ):
         out = np.asarray(fn())
@@ -193,14 +203,18 @@ def _varlen_rows(rng, lens, t):
 
 
 @pytest.mark.parametrize(
-    "b,t,h,kv,d,bs,window",
-    [(4, 4, 4, 2, 16, 8, None), (3, 8, 4, 4, 32, 4, None),
-     (5, 3, 2, 1, 8, 16, None), (2, 6, 8, 2, 16, 8, 5),
-     (4, 5, 4, 2, 16, 8, 12), (6, 2, 2, 2, 8, 4, None)],
+    "b,t,h,kv,d,bs,window,layers,layer",
+    layer_cases(
+        [(4, 4, 4, 2, 16, 8, None), (3, 8, 4, 4, 32, 4, None),
+         (5, 3, 2, 1, 8, 16, None), (2, 6, 8, 2, 16, 8, 5),
+         (4, 5, 4, 2, 16, 8, 12), (6, 2, 2, 2, 8, 4, None)],
+        (4, 5, 4, 2, 16, 8, 12)),
 )
-def test_paged_attention_varlen_ragged_sweep(b, t, h, kv, d, bs, window):
+def test_paged_attention_varlen_ragged_sweep(b, t, h, kv, d, bs, window,
+                                             layers, layer):
     """Varlen Pallas kernel (interpret) vs the jnp oracle on shuffled
-    tables with mixed decode/tile/dead rows at ragged offsets."""
+    tables with mixed decode/tile/dead rows at ragged offsets; the kernel
+    reads layer ``layer`` of the whole pool, the oracle that layer."""
     rng = np.random.default_rng(b * 131 + t * 7 + h)
     num_blocks, max_blocks = 24, 4
     ks = jax.random.split(jax.random.fold_in(KEY, b * t * h + d), 3)
@@ -210,8 +224,9 @@ def test_paged_attention_varlen_ragged_sweep(b, t, h, kv, d, bs, window):
     tables, lens = _ragged_tables(rng, b, num_blocks, max_blocks, bs)
     row_start, row_len = _varlen_rows(rng, lens, t)
     got = paged_attention_varlen(
-        q, kp, vp, jnp.asarray(tables), jnp.asarray(row_start),
-        jnp.asarray(row_len), window=window, interpret=True)
+        q, layered(kp, layers, layer), layered(vp, layers, layer),
+        jnp.asarray(tables), jnp.asarray(row_start), jnp.asarray(row_len),
+        layer=layer, window=window, interpret=True)
     want = ref.ref_paged_attention_varlen(
         q, kp, vp, jnp.asarray(tables), jnp.asarray(row_start),
         jnp.asarray(row_len), window=window)
@@ -237,13 +252,14 @@ def test_paged_attention_varlen_subsumes_decode_and_verify():
     vp = jax.random.normal(ks[2], (kv, num_blocks, bs, d))
     tables, lens = _ragged_tables(rng, b, num_blocks, max_blocks, bs)
     tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+    kp, vp = kp[None], vp[None]
 
     # decode: the varlen row (row_start = ctx-1, row_len = 1) vs the
     # single-token kernel on the same contexts.
     dec = paged_attention_varlen(
         q[:, :1], kp, vp, tables, lens - 1, jnp.ones((b,), jnp.int32),
-        interpret=True)
-    want_dec = paged_attention(q[:, 0], kp, vp, tables, lens,
+        layer=0, interpret=True)
+    want_dec = paged_attention(q[:, 0], kp, vp, tables, lens, layer=0,
                                interpret=True)
     np.testing.assert_allclose(np.asarray(dec[:, 0]), np.asarray(want_dec),
                                rtol=2e-5, atol=2e-5)
@@ -251,13 +267,13 @@ def test_paged_attention_varlen_subsumes_decode_and_verify():
     # verify: the fixed-T wrapper is literally the varlen kernel with
     # (ctx-T, T) rows — including its treatment of inactive slots.
     lens_inact = lens.at[1].set(0)
-    multi = paged_attention_multi(q, kp, vp, tables, lens_inact,
+    multi = paged_attention_multi(q, kp, vp, tables, lens_inact, layer=0,
                                   interpret=True)
     active = lens_inact > 0
     var = paged_attention_varlen(
         q, kp, vp, tables,
         jnp.where(active, lens_inact - t, 0),
-        jnp.where(active, t, 0), interpret=True)
+        jnp.where(active, t, 0), layer=0, interpret=True)
     np.testing.assert_allclose(np.asarray(multi), np.asarray(var),
                                rtol=0, atol=0)
     np.testing.assert_array_equal(np.asarray(multi[1]), 0.0)
@@ -265,20 +281,21 @@ def test_paged_attention_varlen_subsumes_decode_and_verify():
 
 def test_ops_varlen_dispatch_modes_agree():
     """reference and pallas_interpret modes of the ops-layer varlen
-    entry agree on ragged mixed-shape rows."""
+    entry agree on ragged mixed-shape rows, at a middle layer of a
+    three-layer pool."""
     rng = np.random.default_rng(13)
     b, t, h, kv, d, bs = 5, 3, 4, 2, 16, 4
     num_blocks, max_blocks = 16, 4
     ks = jax.random.split(jax.random.fold_in(KEY, 1933), 3)
     q = jax.random.normal(ks[0], (b, t, h, d))
-    kp = jax.random.normal(ks[1], (kv, num_blocks, bs, d))
-    vp = jax.random.normal(ks[2], (kv, num_blocks, bs, d))
+    kp = jax.random.normal(ks[1], (3, kv, num_blocks, bs, d))
+    vp = jax.random.normal(ks[2], (3, kv, num_blocks, bs, d))
     tables, lens = _ragged_tables(rng, b, num_blocks, max_blocks, bs)
     row_start, row_len = _varlen_rows(rng, lens, t)
     args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(row_start),
             jnp.asarray(row_len))
-    a = ops.paged_attention_varlen(*args, mode="reference")
-    bI = ops.paged_attention_varlen(*args, mode="pallas_interpret")
+    a = ops.paged_attention_varlen(*args, layer=1, mode="reference")
+    bI = ops.paged_attention_varlen(*args, layer=1, mode="pallas_interpret")
     np.testing.assert_allclose(np.asarray(a), np.asarray(bI),
                                rtol=2e-5, atol=2e-5)
 

@@ -1,7 +1,10 @@
 """In-place paged KV pool: kernel parity, aliased-vs-carried decode
 bit-exactness (incl. preemption/retire churn), prefill tile writes, and
 the donation/buffer-reuse contract the flat-in-num_blocks cost rests on."""
+import dataclasses
+
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -182,6 +185,65 @@ def test_engine_aliased_matches_carried_under_preemption(
     carried = _run(tf_mod.decode_step_paged_carried)
     for a, c in zip(aliased, carried):
         np.testing.assert_array_equal(a, c)
+
+
+# --- the attention read: the whole pool, one trace --------------------------
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("step", ["decode", "multi", "varlen"])
+def test_attention_reads_whole_pool_with_one_trace(step):
+    """Every paged-attention ``pallas_call`` of a paged step receives K
+    and V as the whole ``[L, KV, NB, BS, lanes]`` pool, nothing in the
+    step has the shape of one layer's slice of it (which a
+    ``pallas_call`` operand would make XLA copy out every round), and
+    all layers share one cached trace of the kernel: the layer is an
+    argument, not a static value, so it is lowered once per shape."""
+    cfg = dataclasses.replace(CFG, n_layers=3)
+    b, t, nb, bs, m = 3, 2, 12, 4, 4
+    params = jax.eval_shape(build(cfg).init, jax.random.PRNGKey(0))
+    pages = jax.eval_shape(lambda: tf_mod.init_paged_cache(cfg, nb, bs))
+    pool = pages["k_pages"].shape
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    kw = {"kernel_mode": "pallas_interpret"}
+    if step == "decode":
+        fn = lambda p, pg, tb: tf_mod.decode_step_paged(
+            p, cfg, jnp.zeros((b,), jnp.int32), pg, tb,
+            jnp.zeros((b,), jnp.int32), jnp.ones((b,), bool), **kw)
+    elif step == "multi":
+        fn = lambda p, pg, tb: tf_mod.decode_step_paged_multi(
+            p, cfg, jnp.zeros((b, t), jnp.int32), pg, tb,
+            jnp.zeros((b,), jnp.int32), jnp.ones((b,), bool),
+            jnp.full((b,), m * bs, jnp.int32), **kw)
+    else:
+        fn = lambda p, pg, tb: tf_mod.decode_step_paged_varlen(
+            p, cfg, jnp.zeros((b, t), jnp.int32), pg, tb,
+            jnp.zeros((b,), jnp.int32), jnp.full((b,), t, jnp.int32),
+            jnp.full((b,), m * bs, jnp.int32), **kw)
+    jaxpr = jax.make_jaxpr(fn)(params, pages, i32(b, m)).jaxpr
+
+    eqns = list(_eqns(jaxpr))
+    assert not [e for e in eqns for v in e.outvars
+                if getattr(v.aval, "shape", None) == pool[1:]]
+    kernels = [e.params["jaxpr"] for e in eqns
+               if e.params.get("name") == "paged_attention_varlen"]
+    assert len(kernels) == cfg.n_layers
+    assert all(k is kernels[0] for k in kernels)
+    calls = [e for e in _eqns(kernels[0].jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert [v.aval.shape for v in calls[0].invars[-2:]] == [pool, pool]
 
 
 # --- prefill tile writes ----------------------------------------------------

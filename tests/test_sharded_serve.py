@@ -48,17 +48,22 @@ def _mesh(data=2):
 # --- shard_map kernel parity vs the single-device oracles -------------------
 
 
+POOL_LAYERS = 3
+
+
 def _ragged_sharded_case(seed, *, shards, per_shard, bs, b, kv, d, h,
                          t=1):
     """A ragged batch whose per-slot pages cross page (and shard-table)
     boundaries: each slot lives on one shard, owns a random *permuted*
     set of that shard's pages, and has its own context length (0 = an
-    inactive slot — included on purpose)."""
+    inactive slot — included on purpose).  The pools are whole
+    ``[L, KV, NB, BS, D]`` pools of ``POOL_LAYERS`` distinct layers."""
     rng = np.random.default_rng(seed)
     nb = shards * per_shard
     m = per_shard                                    # table width
-    k_pages = rng.normal(size=(kv, nb, bs, d)).astype(np.float32)
-    v_pages = rng.normal(size=(kv, nb, bs, d)).astype(np.float32)
+    shape = (POOL_LAYERS, kv, nb, bs, d)
+    k_pages = rng.normal(size=shape).astype(np.float32)
+    v_pages = rng.normal(size=shape).astype(np.float32)
     local_tables = np.stack(
         [rng.permutation(per_shard)[:m] for _ in range(b)]).astype(np.int32)
     slot_shard = (rng.permutation(b) % shards).astype(np.int32)
@@ -80,13 +85,16 @@ def test_sharded_paged_attention_parity(mode, window):
         _ragged_sharded_case(0, shards=2, per_shard=4, bs=4, b=5, kv=2,
                              d=8, h=4)
     q1 = q[:, 0]
-    want = kops.paged_attention(
-        q1, k_pages, v_pages, global_t, lens, window=window, mode=mode)
-    got = kops.paged_attention(
-        q1, k_pages, v_pages, local_t, lens, window=window, mode=mode,
-        mesh=mesh, slot_shard=jnp.asarray(ss))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
+    for layer in range(POOL_LAYERS):
+        want = kops.paged_attention(
+            q1, k_pages, v_pages, global_t, lens, layer=layer,
+            window=window, mode=mode)
+        got = kops.paged_attention(
+            q1, k_pages, v_pages, local_t, lens, layer=layer,
+            window=window, mode=mode, mesh=mesh,
+            slot_shard=jnp.asarray(ss))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("mode", ["reference", "pallas_interpret"])
@@ -97,13 +105,14 @@ def test_sharded_paged_attention_multi_parity(mode):
                              d=8, h=4, t=3)
     lens = np.maximum(lens, 0)
     lens[lens > 0] = np.maximum(lens[lens > 0], 3)   # room for the chunk
-    want = kops.paged_attention_multi(
-        q, k_pages, v_pages, global_t, lens, mode=mode)
-    got = kops.paged_attention_multi(
-        q, k_pages, v_pages, local_t, lens, mode=mode,
-        mesh=mesh, slot_shard=jnp.asarray(ss))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
+    for layer in range(POOL_LAYERS):
+        want = kops.paged_attention_multi(
+            q, k_pages, v_pages, global_t, lens, layer=layer, mode=mode)
+        got = kops.paged_attention_multi(
+            q, k_pages, v_pages, local_t, lens, layer=layer, mode=mode,
+            mesh=mesh, slot_shard=jnp.asarray(ss))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("mode", ["reference", "pallas_interpret"])

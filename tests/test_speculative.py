@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _layered_pool import layer_cases, layered
 from repro.configs.base import ModelConfig
 from repro.data.tokenizer import PAD, get_tokenizer
 from repro.kernels import ref
@@ -66,24 +67,27 @@ def _ragged_tables(rng, b, num_blocks, max_blocks, bs, t):
     return jnp.asarray(tables), jnp.asarray(lens)
 
 
-@pytest.mark.parametrize("b,t,h,kv,d,bs,window", [
+@pytest.mark.parametrize("b,t,h,kv,d,bs,window,layers,layer", layer_cases([
     (2, 4, 4, 2, 16, 4, None),
     (3, 2, 2, 2, 8, 4, None),
     (2, 3, 4, 4, 16, 8, None),
     (2, 4, 4, 2, 16, 4, 6),
     (1, 5, 8, 2, 32, 4, None),
-])
-def test_paged_attention_multi_parity_sweep(b, t, h, kv, d, bs, window):
+], (2, 4, 4, 2, 16, 4, 6)))
+def test_paged_attention_multi_parity_sweep(b, t, h, kv, d, bs, window,
+                                            layers, layer):
     """Pallas multi-query kernel (interpret) vs jnp oracle on shuffled,
-    ragged block tables."""
+    ragged block tables; the kernel reads layer ``layer`` of the whole
+    pool in place, the oracle that layer alone."""
     rng = np.random.default_rng(b * 17 + t)
     ks = jax.random.split(jax.random.fold_in(KEY, b * t * d), 3)
     q = jax.random.normal(ks[0], (b, t, h, d))
     kp = jax.random.normal(ks[1], (kv, 24, bs, d))
     vp = jax.random.normal(ks[2], (kv, 24, bs, d))
     tables, lens = _ragged_tables(rng, b, 24, 4, bs, t)
-    out = paged_attention_multi(q, kp, vp, tables, lens,
-                                window=window, interpret=True)
+    out = paged_attention_multi(q, layered(kp, layers, layer),
+                                layered(vp, layers, layer), tables, lens,
+                                layer=layer, window=window, interpret=True)
     want = ref.ref_paged_attention_multi(q, kp, vp, tables, lens,
                                          window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -98,8 +102,11 @@ def test_paged_attention_multi_t1_reduces_to_single():
     vp = jax.random.normal(ks[2], (2, 8, 4, 16))
     tables = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
     lens = jnp.asarray([7, 3], jnp.int32)
-    multi = paged_attention_multi(q, kp, vp, tables, lens, interpret=True)
-    single = paged_attention(q[:, 0], kp, vp, tables, lens, interpret=True)
+    kp, vp = kp[None], vp[None]
+    multi = paged_attention_multi(q, kp, vp, tables, lens, layer=0,
+                                  interpret=True)
+    single = paged_attention(q[:, 0], kp, vp, tables, lens, layer=0,
+                             interpret=True)
     np.testing.assert_allclose(np.asarray(multi[:, 0]), np.asarray(single),
                                rtol=2e-5, atol=2e-5)
 
@@ -113,8 +120,8 @@ def test_paged_attention_multi_inactive_slot_zero():
     tables = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
     lens = jnp.asarray([0, 6], jnp.int32)
     for fn in (
-        lambda: paged_attention_multi(q, kp, vp, tables, lens,
-                                      interpret=True),
+        lambda: paged_attention_multi(q, kp[None], vp[None], tables, lens,
+                                      layer=0, interpret=True),
         lambda: ref.ref_paged_attention_multi(q, kp, vp, tables, lens),
     ):
         out = np.asarray(fn())

@@ -68,22 +68,27 @@ def _on(sharding, shape, dtype=jnp.float32):
 
 @pytest.mark.parametrize("t", [1, 16])
 def test_paged_attention_varlen_compiles(one_chip, t):
+    """The kernel reads the whole pool at a traced layer."""
     i32 = jnp.int32
+    pool = (L, KV, NB, BS, LANES)
     _compile(
-        lambda q, k, v, tb, rs, rl: paged_attention_varlen(
-            q, k, v, tb, rs, rl),
-        _on(one_chip, (B, t, H, D)), _on(one_chip, (KV, NB, BS, LANES)),
-        _on(one_chip, (KV, NB, BS, LANES)), _on(one_chip, (B, M), i32),
-        _on(one_chip, (B,), i32), _on(one_chip, (B,), i32))
+        lambda q, k, v, tb, rs, rl, ly: paged_attention_varlen(
+            q, k, v, tb, rs, rl, layer=ly),
+        _on(one_chip, (B, t, H, D)), _on(one_chip, pool),
+        _on(one_chip, pool), _on(one_chip, (B, M), i32),
+        _on(one_chip, (B,), i32), _on(one_chip, (B,), i32),
+        _on(one_chip, (), i32))
 
 
 def test_paged_attention_decode_compiles(one_chip):
     i32 = jnp.int32
+    pool = (L, KV, NB, BS, LANES)
     _compile(
-        lambda q, k, v, tb, cl: paged_attention(q, k, v, tb, cl),
-        _on(one_chip, (B, H, D)), _on(one_chip, (KV, NB, BS, LANES)),
-        _on(one_chip, (KV, NB, BS, LANES)), _on(one_chip, (B, M), i32),
-        _on(one_chip, (B,), i32))
+        lambda q, k, v, tb, cl, ly: paged_attention(
+            q, k, v, tb, cl, layer=ly),
+        _on(one_chip, (B, H, D)), _on(one_chip, pool),
+        _on(one_chip, pool), _on(one_chip, (B, M), i32),
+        _on(one_chip, (B,), i32), _on(one_chip, (), i32))
 
 
 def test_paged_kv_write_compiles(one_chip):
@@ -117,12 +122,14 @@ def test_sharded_varlen_dispatch_compiles(topo):
     kernel inside ``shard_map`` and a psum recombining the batch."""
     mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
     rep = NamedSharding(mesh, P())
-    pool = NamedSharding(mesh, P(None, "data", None, None))
+    pool = NamedSharding(mesh, P(None, None, "data", None, None))
     i32 = jnp.int32
     text = _compile(
-        lambda q, k, v, tb, rs, rl, ss: ops.paged_attention_varlen(
-            q, k, v, tb, rs, rl, mode="pallas", mesh=mesh, slot_shard=ss),
-        _on(rep, (B, 16, H, D)), _on(pool, (KV, NB, BS, LANES)),
-        _on(pool, (KV, NB, BS, LANES)), _on(rep, (B, M), i32),
-        _on(rep, (B,), i32), _on(rep, (B,), i32), _on(rep, (B,), i32))
+        lambda q, k, v, tb, rs, rl, ly, ss: ops.paged_attention_varlen(
+            q, k, v, tb, rs, rl, layer=ly, mode="pallas", mesh=mesh,
+            slot_shard=ss),
+        _on(rep, (B, 16, H, D)), _on(pool, (L, KV, NB, BS, LANES)),
+        _on(pool, (L, KV, NB, BS, LANES)), _on(rep, (B, M), i32),
+        _on(rep, (B,), i32), _on(rep, (B,), i32), _on(rep, (), i32),
+        _on(rep, (B,), i32))
     assert "all-reduce" in text
