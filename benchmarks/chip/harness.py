@@ -4,8 +4,10 @@ compile clock, the run's records and the result line.
 A cell is ``workloads/<cell>.json``; it names a configuration
 (``configs/<config>.json``), a traffic mix (``traffic/<mix>.json``, read
 by the generator ``traffic/<kind>.py`` that the mix names) and the
-engine's settings.  Each metric is ``metrics/<metric>.py``.  Nothing
-here names a cell, a configuration or a metric.
+engine's settings.  Each metric is ``metrics/<metric>.py``.  What is
+particular to an architecture is ``arch/<class>.py``, found by the class
+the configuration's ``architectures`` names (see ``arch``).  Nothing here
+names a cell, a configuration, an architecture or a metric.
 """
 from __future__ import annotations
 
@@ -15,7 +17,7 @@ import sys
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -53,13 +55,45 @@ def load_mix(name: str) -> Dict[str, Any]:
 
 
 def load_module(kind: str, name: str):
-    """``<kind>/<name>.py`` as a module; names may hold dots."""
+    """``<kind>/<name>.py`` as a module; names may hold dots.  A file is
+    run once per process, so what it compiles is compiled once."""
     path = _named(kind, name, ".py")
-    mod_name = f"_bench_{kind}_{name}".replace(".", "_").replace("-", "_")
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    if path not in _LOADED:
+        mod_name = f"_bench_{kind}_{name}".replace(".", "_").replace("-", "_")
+        spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _LOADED[path] = mod
+    return _LOADED[path]
+
+
+_LOADED: Dict[Path, Any] = {}
+
+
+def arch(conf: Dict[str, Any]):
+    """The module of the configuration's published class,
+    ``arch/<architectures[0]>.py``.  It supplies everything that differs
+    between architectures, each taking the configuration first:
+
+    - weights: ``shapes(conf)``, the parameter tree (leaf shapes, the
+      layers' leaves stacked under ``layers``; ``embed``, ``final_norm``
+      and, untied, ``lm_head`` at the top), and ``scale(name, shape,
+      conf)``, the standard deviation of a layer leaf, or None for a
+      norm's gain;
+    - reference: ``layer_statics(conf, i)``, layer ``i``'s hashable
+      settings (window, rope), and ``layer(x, lw, *, bf16x3, **statics)``,
+      one layer's float32 forward over a whole sequence;
+    - program bridge: ``model_config(conf)`` and ``params(conf, w)``;
+    - work counts: ``dims(conf)``, ``matmul_params(conf)``,
+      ``attention_flops(conf, ctx_rows, slots)`` and
+      ``paged_attention(conf, rows, ctx_rows, kv_rows, slots)``, and any
+      other count a roofline metric divides by (``work.count``).
+    """
+    classes = conf.get("architectures")
+    if not classes:
+        raise SystemExit(f"{conf.get('name')!r}: the configuration names "
+                         "no class under 'architectures'")
+    return load_module("arch", classes[0])
 
 
 def cell_metrics(spec: Dict[str, Any], cell: str) -> Dict[str, List[dict]]:
@@ -136,6 +170,10 @@ class Round:
     rows: int                 # query rows computed (prefill + decode)
     ctx_rows: int             # sum over rows of the context each attends
     kv_rows: int              # sum over slots of the K/V rows read
+    # (live length, rows computed) of each slot that computed rows: its
+    # rows attend contexts live - rows + 1 .. live, and it reads its live
+    # K/V rows.  What a count clipped to a window needs.
+    slots: Tuple[Tuple[int, int], ...] = ()
 
 
 @dataclass

@@ -1,14 +1,16 @@
 """The bridge to the system under test: its model configuration, its
 parameter tree over the benchmark's weights, and its serve engine.
 
-This is the only module of the benchmark that imports the program.
+This module and the bridge half of each ``arch/<class>.py``
+(``model_config``, ``params``) are the only code of the benchmark that
+reaches the program; the architecture's part is called from here.
 """
 from __future__ import annotations
 
-import math
 import sys
 from typing import Any, Dict
 
+import harness
 from harness import ROOT
 
 SRC = ROOT / "src"
@@ -32,64 +34,16 @@ def enable_compile_cache() -> None:
 
 
 def model_config(conf: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file, with every
-    published number the program has a setting for."""
+    """The program's ``ModelConfig`` for a configuration file, from its
+    architecture's module."""
     _import_program()
-    from repro.configs.base import ModelConfig
-
-    if conf["hidden_act"] != "silu":
-        raise SystemExit(f"{conf['name']}: hidden_act "
-                         f"{conf['hidden_act']!r} is not served here")
-    return ModelConfig(
-        name=conf["name"], arch_type="dense",
-        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        d_head=conf["hidden_size"] // conf["num_attention_heads"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        qkv_bias=True, rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]), activation="swiglu",
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        value_head=False, source=conf["source"])
+    return harness.arch(conf).model_config(conf)
 
 
 def params(conf: Dict[str, Any], w: Dict[str, Any]) -> Dict[str, Any]:
-    """The program's parameter tree over the same device buffers.
-
-    The program multiplies token embeddings by ``sqrt(hidden_size)``
-    (``repro.models.transformer._embed_inputs``), which Qwen2 does not.
-    The stored ``embed`` is ``E / sqrt(hidden_size)``, so its lookup
-    gives ``E`` exactly as published; under a tied readout the final
-    norm's gain carries the same factor, so the logits are the published
-    ones too."""
-    import jax.numpy as jnp
-
-    lw = w["layers"]
-    final = w["final_norm"]
-    if conf["tie_word_embeddings"]:
-        final = final * jnp.float32(math.sqrt(conf["hidden_size"]))
-    p = {
-        "embed": {"table": w["embed"]},
-        "final_norm": {"scale": final},
-        "layers": {
-            "norm1": {"scale": lw["ln1"]},
-            "norm2": {"scale": lw["ln2"]},
-            "attn": {
-                "wq": {"w": lw["wq"], "b": lw["bq"]},
-                "wk": {"w": lw["wk"], "b": lw["bk"]},
-                "wv": {"w": lw["wv"], "b": lw["bv"]},
-                "wo": {"w": lw["wo"]},
-            },
-            "mlp": {
-                "gate": {"w": lw["w_gate"]},
-                "up": {"w": lw["w_up"]},
-                "down": {"w": lw["w_down"]},
-            },
-        },
-    }
-    if not conf["tie_word_embeddings"]:
-        p["lm_head"] = {"w": w["lm_head"]}
-    return p
+    """The program's parameter tree over the benchmark's weights (the
+    same device buffers), from the architecture's module."""
+    return harness.arch(conf).params(conf, w)
 
 
 def page_size() -> int:

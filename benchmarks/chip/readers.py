@@ -4,7 +4,7 @@ read returns None, and the metric is left out of the line."""
 from __future__ import annotations
 
 import math
-from typing import Iterable, List, Optional
+from typing import Any, Dict, Iterable, List, Optional
 
 import work
 from harness import RunRecord
@@ -65,19 +65,26 @@ def busy_ms(run: RunRecord, label: str) -> Optional[float]:
     return None if got is None else 1000.0 * got[0] / got[1]
 
 
-def roofline_pct(run: RunRecord, kernels: Iterable[str]) -> Optional[float]:
-    """Share of the paged-attention roofline over the window: the least
-    time the chip needs for the live contexts of every round, over the
-    kernel's device time."""
+def window_work(run: RunRecord) -> Dict[str, Any]:
+    """What every round of the window computed, as the counts of
+    ``work.py`` take it."""
+    return {"rows": sum(r.rows for r in run.rounds),
+            "ctx_rows": sum(r.ctx_rows for r in run.rounds),
+            "kv_rows": sum(r.kv_rows for r in run.rounds),
+            "slots": [s for r in run.rounds for s in r.slots]}
+
+
+def roofline_pct(run: RunRecord, kernels: Iterable[str],
+                 count: str = "paged_attention") -> Optional[float]:
+    """Share of a kernel's roofline over the window: the least time the
+    chip needs for the work of every round, as the architecture's count
+    ``count`` gives it, over the kernel's device time."""
     if run.trace is None or not run.rounds:
         return None
     t = run.trace.op_seconds(kernels)
     if not t:
         return None
-    w = work.paged_attention(run.config,
-                             rows=sum(r.rows for r in run.rounds),
-                             ctx_rows=sum(r.ctx_rows for r in run.rounds),
-                             kv_rows=sum(r.kv_rows for r in run.rounds))
+    w = work.count(run.config, count, **window_work(run))
     least = work.roofline_seconds(w["flops"], w["bytes"], run.peaks)
     run.facts.setdefault("roofline_bound", {})[",".join(kernels)] = \
         least["bound"]
@@ -89,9 +96,10 @@ def mfu_pct(run: RunRecord) -> Optional[float]:
     window's length times the chip's peak."""
     if not run.rounds or run.window_s <= 0:
         return None
-    flops = work.forward_flops(run.config,
-                               rows=sum(r.rows for r in run.rounds),
-                               ctx_rows=sum(r.ctx_rows for r in run.rounds))
+    done = window_work(run)
+    flops = work.forward_flops(run.config, rows=done["rows"],
+                               ctx_rows=done["ctx_rows"],
+                               slots=done["slots"])
     return 100.0 * flops / (run.window_s * run.peaks["flops_per_s"])
 
 

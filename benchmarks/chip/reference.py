@@ -1,8 +1,11 @@
-"""Plain Qwen2 forward pass (``Qwen2ForCausalLM``), in jax.numpy.
+"""Plain forward pass of a causal language model, in jax.numpy.
 
 No kernel, cache or batching: one sequence, every position, layer by
 layer, so that a long sequence of the widest model fits beside its
-weights.  Every product is float32 at ``highest`` precision.  The
+weights.  The layer is the architecture's (``layer`` and
+``layer_statics`` of ``arch/<class>.py``), written with ``mm``, ``rms``
+and ``rope`` from here; the embedding, the readout and the loop are
+shared.  Every product is float32 at ``highest`` precision.  The
 control (``bf16x3=True``) takes each product in three bfloat16 passes
 (``hi*hi + hi*lo + lo*hi`` with float32 sums), which is what the TPU's
 ``high`` precision does, written out so that it runs the same on any
@@ -20,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import harness
+
 # Sequences are padded to a multiple of this, so a handful of programs
 # serve every length; causal attention keeps the padding out of the
 # positions that are read.
@@ -35,7 +40,7 @@ def _split(a):
     return hi.astype(jnp.bfloat16), lo.astype(jnp.bfloat16)
 
 
-def _mm(spec, a, b, bf16x3):
+def mm(spec, a, b, bf16x3):
     """``einsum(spec, a, b)``: float32 at ``highest``, or three bfloat16
     passes with float32 sums."""
     if not bf16x3:
@@ -46,13 +51,13 @@ def _mm(spec, a, b, bf16x3):
     return dot(ah, bh) + (dot(ah, bl) + dot(al, bh))
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
     return (y * g.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """Rotate-half rotary embedding at positions 0..S-1; x [S, N, dh]."""
     s, _, dh = x.shape
     inv = 1.0 / (theta ** (np.arange(0, dh, 2, dtype=np.float64) / dh))
@@ -66,40 +71,14 @@ def _rope(x, theta):
     return (x32 * cos + rot * sin).astype(x.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "kv", "eps",
-                                             "theta", "bf16x3"))
-def _layer(x, lw, *, heads, kv, eps, theta, bf16x3):
-    s, d = x.shape
-    dh = d // heads
-    mm = functools.partial(_mm, bf16x3=bf16x3)
-    h = _rms(x, lw["ln1"], eps)
-    q = (mm("sd,de->se", h, lw["wq"]) + lw["bq"]).reshape(s, heads, dh)
-    k = (mm("sd,de->se", h, lw["wk"]) + lw["bk"]).reshape(s, kv, dh)
-    v = (mm("sd,de->se", h, lw["wv"]) + lw["bv"]).reshape(s, kv, dh)
-    q, k = _rope(q, theta), _rope(k, theta)
-    group = heads // kv
-    k = jnp.repeat(k, group, axis=1)          # head j reads kv j // group
-    v = jnp.repeat(v, group, axis=1)
-    scores = mm("qhd,khd->hqk", q, k) / math.sqrt(dh)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    probs = jax.nn.softmax(jnp.where(causal[None], scores, -jnp.inf), -1)
-    o = mm("hqk,khd->qhd", probs, v).reshape(s, heads * dh)
-    x = x + mm("se,ed->sd", o, lw["wo"])
-    h = _rms(x, lw["ln2"], eps)
-    gate = jax.nn.silu(mm("sd,df->sf", h, lw["w_gate"]))
-    x = x + mm("sf,fd->sd", gate * mm("sd,df->sf", h, lw["w_up"]),
-               lw["w_down"])
-    return x
-
-
 @functools.partial(jax.jit, static_argnames=("eps", "tied", "bf16x3"))
 def _head(x, rows, final, head, *, eps, tied, bf16x3):
     """Logits at ``rows`` of the last hidden states."""
     d = x.shape[-1]
-    h = _rms(x[rows], final, eps)
+    h = rms(x[rows], final, eps)
     if tied:   # E^T = sqrt(d) * embed^T
-        return _mm("sd,vd->sv", h, head, bf16x3) * math.sqrt(d)
-    return _mm("sd,dv->sv", h, head, bf16x3)
+        return mm("sd,vd->sv", h, head, bf16x3) * math.sqrt(d)
+    return mm("sd,dv->sv", h, head, bf16x3)
 
 
 @jax.jit
@@ -117,17 +96,15 @@ def logits_at(w: Dict[str, Any], conf: Dict[str, Any], ids: np.ndarray,
     n_rows = -(-len(rows) // BUCKET) * BUCKET
     rows_p = np.full((n_rows,), rows[-1], np.int32)
     rows_p[:len(rows)] = rows
-    kw = dict(heads=conf["num_attention_heads"],
-              kv=conf["num_key_value_heads"],
-              eps=float(conf["rms_norm_eps"]),
-              theta=float(conf["rope_theta"]), bf16x3=bf16x3)
+    arch = harness.arch(conf)
     x = _embed(w["embed"], jnp.asarray(ids_p))
     for i in range(conf["num_hidden_layers"]):
-        x = _layer(x, {k: v[i] for k, v in w["layers"].items()}, **kw)
+        x = arch.layer(x, {k: v[i] for k, v in w["layers"].items()},
+                       bf16x3=bf16x3, **arch.layer_statics(conf, i))
     tied = conf["tie_word_embeddings"]
     out = _head(x, jnp.asarray(rows_p), w["final_norm"],
                 w["embed"] if tied else w["lm_head"],
-                eps=kw["eps"], tied=tied, bf16x3=bf16x3)
+                eps=float(conf["rms_norm_eps"]), tied=tied, bf16x3=bf16x3)
     return out[:len(rows)]
 
 

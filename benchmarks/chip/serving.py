@@ -50,22 +50,25 @@ class Client:
         t1 = now()
         if self.recording:
             rows = ctx = kv = 0
+            slots = []
             for r, pd0, np0, nt0 in before:
                 n = r.num_prefilled - np0
                 if n > 0:            # a prefill tile of n rows from np0
                     rows += n
                     ctx += n * np0 + n * (n + 1) // 2
                     kv += np0 + n
+                    slots.append((np0 + n, n))
                 if pd0 and len(r.tokens) > nt0:
                     c = len(r.prompt) + nt0   # one decode row
                     rows += 1
                     ctx += c
                     kv += c
+                    slots.append((c, 1))
             alloc = self.eng.allocator
             self.rec.rounds.append(Round(
                 t0=t0, t1=t1, running=len(self.eng.scheduler.running),
                 pages_used=alloc.num_blocks - alloc.num_free,
-                rows=rows, ctx_rows=ctx, kv_rows=kv))
+                rows=rows, ctx_rows=ctx, kv_rows=kv, slots=tuple(slots)))
         finished = [self.by_id[t.request_id] for t in done]
         if finished:
             ids = {id(s.req) for s in finished}

@@ -1,19 +1,23 @@
 """The benchmark's own weights, made on the device from the seed.
 
-Layout is the published Qwen2 one (``Qwen2ForCausalLM``), stacked over
-layers, with one storage convention: the token embedding is kept as
-``embed = E / sqrt(hidden_size)``, where ``E`` is the published
+The parameter tree and each layer leaf's scale are the architecture's
+(``shapes`` and ``scale`` of ``arch/<class>.py``), stacked over layers;
+the embedding, the final norm and the readout are made here for every
+architecture, with one storage convention: the token embedding is kept
+as ``embed = E / sqrt(hidden_size)``, where ``E`` is the published
 embedding.  The reference multiplies it back.  Nothing here imports the
 program; ``program.py`` hands the same buffers to it.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import harness
 
 
 def key_from_seed(seed: int) -> jax.Array:
@@ -26,28 +30,8 @@ def key_from_seed(seed: int) -> jax.Array:
 
 
 def shapes(conf: Dict[str, Any]) -> Dict[str, Any]:
-    d = conf["hidden_size"]
-    h = conf["num_attention_heads"]
-    kv = conf["num_key_value_heads"]
-    dh = d // h
-    f = conf["intermediate_size"]
-    v = conf["vocab_size"]
-    n = conf["num_hidden_layers"]
-    out = {
-        "embed": (v, d),
-        "final_norm": (d,),
-        "layers": {
-            "ln1": (n, d), "ln2": (n, d),
-            "wq": (n, d, h * dh), "bq": (n, h * dh),
-            "wk": (n, d, kv * dh), "bk": (n, kv * dh),
-            "wv": (n, d, kv * dh), "bv": (n, kv * dh),
-            "wo": (n, h * dh, d),
-            "w_gate": (n, d, f), "w_up": (n, d, f), "w_down": (n, f, d),
-        },
-    }
-    if not conf["tie_word_embeddings"]:
-        out["lm_head"] = (d, v)
-    return out
+    """Every leaf's shape, from the architecture's module."""
+    return harness.arch(conf).shapes(conf)
 
 
 # Query and key projections QK_GAIN times wider than unit fan-in make
@@ -72,41 +56,43 @@ LOGIT_STD = 2.0
 EOS_ID = 2
 
 
-def _std(name: str, shape, conf) -> float:
-    """Scale of each leaf: unit-variance activations through every
-    layer, logits of order one, biases and norm gains away from their
-    trivial values so that each is exercised."""
-    d = conf["hidden_size"]
-    if name == "embed":
-        return EMBED_STD * d ** -0.5    # E itself is N(0, EMBED_STD**2)
-    if name in ("bq", "bk", "bv"):
-        return 0.1
-    gain = {"wq": QK_GAIN, "wk": QK_GAIN, "lm_head": LOGIT_STD}.get(name, 1.0)
-    return gain * shape[-2] ** -0.5  # fan-in of a [.., in, out] matrix
+def _std(arch, name: str, shape, conf) -> Optional[float]:
+    """Scale of each leaf, or None for a norm's gain: unit-variance
+    activations through every layer, logits of order one, biases and
+    norm gains away from their trivial values so that each is
+    exercised.  A layer's leaves are the architecture's."""
+    if name == "embed":    # E itself is N(0, EMBED_STD**2)
+        return EMBED_STD * conf["hidden_size"] ** -0.5
+    if name == "lm_head":  # fan-in of the [d, vocab] readout
+        return LOGIT_STD * shape[-2] ** -0.5
+    if name == "final_norm":
+        return None
+    return arch.scale(name, shape, conf)
 
 
 def make(conf: Dict[str, Any], seed: int) -> Dict[str, Any]:
     """All weights in one jitted call on the default device, float32."""
-    tree = shapes(conf)
+    arch = harness.arch(conf)
     flat, treedef = jax.tree.flatten_with_path(
-        tree, is_leaf=lambda x: isinstance(x, tuple))
+        arch.shapes(conf), is_leaf=lambda x: isinstance(x, tuple))
     names = [str(p[-1].key) for p, _ in flat]
     leaf_shapes = [s for _, s in flat]
+    stds = [_std(arch, n, s, conf) for n, s in zip(names, leaf_shapes)]
     d = conf["hidden_size"]
     tied = conf["tie_word_embeddings"]
 
     def gen(key):
         keys = jax.random.split(key, len(leaf_shapes))
         out = []
-        for k, name, shape in zip(keys, names, leaf_shapes):
+        for k, name, shape, std in zip(keys, names, leaf_shapes, stds):
             z = jax.random.normal(k, shape, jnp.float32)
-            if name in ("ln1", "ln2", "final_norm"):
+            if std is None:
                 w = 1.0 + 0.1 * z
                 if name == "final_norm" and tied:
                     # Rows of E have norm EMBED_STD * sqrt(hidden_size).
                     w = w * (LOGIT_STD / (EMBED_STD * math.sqrt(d)))
             else:
-                w = z * _std(name, shape, conf)
+                w = z * std
             if name == "lm_head":
                 w = w.at[:, EOS_ID].set(0.0)
             elif name == "embed" and tied:

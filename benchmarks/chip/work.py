@@ -3,58 +3,62 @@
 Counted at ``head_dim``, never at the 128 lanes the pool pads a row to,
 and over live context only: a layout that moves more bytes, or a grid
 that visits dead pages, reads below 100% and cannot push a share over.
+The counts are the architecture's (``arch/<class>.py``); this module
+finds them by the configuration.  ``slots`` is every ``(live length,
+rows computed)`` pair of the window's rounds (``harness.Round.slots``),
+which a count clipped to a window per layer reads; one whose layers all
+see the whole context reads the sums alone.
 """
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Iterable, Optional, Tuple
+
+import harness
 
 F32 = 4
+Slots = Optional[Iterable[Tuple[int, int]]]
+
+
+def count(conf: Dict[str, Any], name: str, **work) -> Dict[str, int]:
+    """The architecture's count ``name`` (``flops`` and ``bytes``) for
+    the window's ``rows``, ``ctx_rows``, ``kv_rows`` and ``slots``."""
+    fn = getattr(harness.arch(conf), name, None)
+    if fn is None:
+        raise SystemExit(f"{conf['architectures'][0]} has no count "
+                         f"{name!r}")
+    return fn(conf, **work)
 
 
 def dims(conf: Dict[str, Any]) -> Dict[str, int]:
-    d = conf["hidden_size"]
-    h = conf["num_attention_heads"]
-    return {"d": d, "h": h, "kv": conf["num_key_value_heads"],
-            "dh": d // h, "f": conf["intermediate_size"],
-            "v": conf["vocab_size"], "layers": conf["num_hidden_layers"]}
+    return harness.arch(conf).dims(conf)
 
 
 def matmul_params(conf: Dict[str, Any]) -> int:
-    """Weights that each token multiplies: every projection of every
-    layer and the readout (biases and norms are not matmuls)."""
-    x = dims(conf)
-    per_layer = (x["d"] * x["dh"] * (x["h"] + 2 * x["kv"])   # q, k, v
-                 + x["h"] * x["dh"] * x["d"]                  # o
-                 + 3 * x["d"] * x["f"])                       # gate, up, down
-    return x["layers"] * per_layer + x["d"] * x["v"]
+    """Weights that each token multiplies: for experts, only those a
+    token is routed to (biases and norms are not matmuls)."""
+    return harness.arch(conf).matmul_params(conf)
 
 
-def attention_flops(conf: Dict[str, Any], ctx_rows: int) -> int:
-    """Scores and weighted sum over all layers: 2 * 2 * heads * head_dim
-    per (query row, context row) pair; ``ctx_rows`` sums each row's
-    context."""
-    x = dims(conf)
-    return 4 * x["h"] * x["dh"] * ctx_rows * x["layers"]
+def attention_flops(conf: Dict[str, Any], ctx_rows: int,
+                    slots: Slots = None) -> int:
+    """Scores and weighted sum over all layers, for the (query row,
+    context row) pairs that each layer attends."""
+    return harness.arch(conf).attention_flops(conf, ctx_rows, slots)
 
 
-def forward_flops(conf: Dict[str, Any], rows: int, ctx_rows: int) -> int:
+def forward_flops(conf: Dict[str, Any], rows: int, ctx_rows: int,
+                  slots: Slots = None) -> int:
     """Model operations to run ``rows`` tokens forward (2 per weight per
     token), attention included."""
-    return 2 * matmul_params(conf) * rows + attention_flops(conf, ctx_rows)
+    return (2 * matmul_params(conf) * rows
+            + attention_flops(conf, ctx_rows, slots))
 
 
 def paged_attention(conf: Dict[str, Any], rows: int, ctx_rows: int,
-                    kv_rows: int) -> Dict[str, int]:
-    """One paged-attention call per layer, summed over the layers: query
-    ``rows`` attending ``ctx_rows`` (row, context) pairs, reading each
-    slot's ``kv_rows`` live K and V rows once, writing one output row per
-    query row."""
-    x = dims(conf)
-    flops = attention_flops(conf, ctx_rows)
-    kv_bytes = 2 * x["kv"] * x["dh"] * F32 * kv_rows
-    qo_bytes = 2 * x["h"] * x["dh"] * F32 * rows
-    return {"flops": flops,
-            "bytes": (kv_bytes + qo_bytes) * x["layers"]}
+                    kv_rows: int, slots: Slots = None) -> Dict[str, int]:
+    """One paged-attention call per layer, summed over the layers."""
+    return count(conf, "paged_attention", rows=rows, ctx_rows=ctx_rows,
+                 kv_rows=kv_rows, slots=slots)
 
 
 def roofline_seconds(flops: float, nbytes: float,

@@ -70,3 +70,25 @@ def test_trace_metrics_leave_nothing_when_nothing_ran():
     assert readers.roofline_pct(run, ["paged"]) is None
     assert readers.busy_ms(run, "bench.step") is None
     assert readers.idle_pct(run) == pytest.approx(25.0)
+
+
+def test_roofline_divides_by_the_named_count():
+    import xplane
+
+    rounds = [Round(0, 1, running=2, pages_used=1, rows=17, ctx_rows=266,
+                    kv_rows=146, slots=((130, 1), (16, 16)))]
+    run = _run(rounds=rounds)
+    run.trace = xplane.Reduced(window_s=2.0, busy_s=1.5,
+                               ops={"%paged_attention_varlen.1": [1e-3, 1]})
+    done = readers.window_work(run)
+    assert done == {"rows": 17, "ctx_rows": 266, "kv_rows": 146,
+                    "slots": [(130, 1), (16, 16)]}
+    w = work.paged_attention(CONF, 17, 266, 146)
+    least = work.roofline_seconds(w["flops"], w["bytes"], PEAKS)["seconds"]
+    kernels = [r"^%paged_attention_varlen\b"]
+    assert readers.roofline_pct(run, kernels) == pytest.approx(
+        100 * least / 1e-3)
+    assert readers.roofline_pct(run, kernels, "paged_attention") == \
+        readers.roofline_pct(run, kernels)
+    with pytest.raises(SystemExit, match="no_such_count"):
+        readers.roofline_pct(run, kernels, "no_such_count")

@@ -15,7 +15,8 @@ import harness
 import run
 
 TINY = dict(name="tiny", source="https://example.org/tiny",
-            hidden_size=128, intermediate_size=256, num_attention_heads=4,
+            architectures=["Qwen2ForCausalLM"], hidden_size=128,
+            intermediate_size=256, num_attention_heads=4,
             num_key_value_heads=2, num_hidden_layers=2, vocab_size=512,
             rope_theta=1e6, rms_norm_eps=1e-6, tie_word_embeddings=True,
             hidden_act="silu", matmul_precision="highest")
